@@ -31,6 +31,7 @@ from benchmarks import (bench_baselines, bench_compression, bench_faults,
                         bench_logreg, bench_nn, bench_roofline,
                         bench_sensitivity, bench_serve, bench_theory)
 from benchmarks.common import drain_rows, write_json
+from repro.utils.compile_cache import use_compile_cache
 
 ALL = {
     "linreg": bench_linreg.main,
@@ -62,6 +63,7 @@ def main() -> None:
         os.makedirs(json_dir, exist_ok=True)
 
     names = args or list(ALL)
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for n in names:

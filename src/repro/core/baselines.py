@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.compression import rel_err as _rel_err
-from repro.core.gossip import DenseGossip
+from repro.core.gossip import HIGHEST, DenseGossip
 from repro.core.lead import Schedule, _at
 
 
@@ -275,7 +275,7 @@ class CEDAS:
             W = jnp.asarray(self.topology.Ws, v.dtype)[r]
         else:
             W = jnp.asarray(self.topology.W, v.dtype)
-        return W @ v
+        return jnp.matmul(W, v, precision=HIGHEST)
 
     def init(self, x0, g0, key):
         return DiffusionState(x=x0, psi_prev=x0, h=x0,
@@ -372,7 +372,7 @@ class CGT:
             W = jnp.asarray(self.topology.Ws, v.dtype)[r]
         else:
             W = jnp.asarray(self.topology.W, v.dtype)
-        return W @ v
+        return jnp.matmul(W, v, precision=HIGHEST)
 
     def init(self, x0, g0, key):
         z = jnp.zeros_like(x0)

@@ -23,6 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# The objectives' own arithmetic runs at full f32 precision: at a TPU's
+# default one-pass bf16 matmul the gradient at x* is a rounding error
+# away from zero, and the optimum the algorithms reach moves with it.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class LinearRegression:
@@ -35,7 +40,8 @@ class LinearRegression:
         k1, k2, k3 = jax.random.split(key, 3)
         A = jax.random.normal(k1, (n_agents, m, d)) / jnp.sqrt(m)
         x_true = jax.random.normal(k2, (d,))
-        b = jnp.einsum("nmd,d->nm", A, x_true) + noise * jax.random.normal(k3, (n_agents, m))
+        b = (jnp.einsum("nmd,d->nm", A, x_true, precision=HIGHEST)
+             + noise * jax.random.normal(k3, (n_agents, m)))
         return LinearRegression(A=A, b=b, lam=lam)
 
     @property
@@ -48,39 +54,44 @@ class LinearRegression:
 
     def local_grad(self, i, x):
         Ai, bi = self.A[i], self.b[i]
-        return 2.0 * Ai.T @ (Ai @ x - bi) + 2.0 * self.lam * x
+        r = jnp.matmul(Ai, x, precision=HIGHEST) - bi
+        return (2.0 * jnp.matmul(Ai.T, r, precision=HIGHEST)
+                + 2.0 * self.lam * x)
 
     def full_grad(self, X):
         """X: (n, d) -> per-agent gradients (n, d)."""
-        r = jnp.einsum("nmd,nd->nm", self.A, X) - self.b
-        return 2.0 * jnp.einsum("nmd,nm->nd", self.A, r) + 2.0 * self.lam * X
+        r = jnp.einsum("nmd,nd->nm", self.A, X, precision=HIGHEST) - self.b
+        return (2.0 * jnp.einsum("nmd,nm->nd", self.A, r, precision=HIGHEST)
+                + 2.0 * self.lam * X)
 
     def minibatch_grad(self, X, key, batch=32):
         n, m, d = self.A.shape
         idx = jax.random.randint(key, (n, batch), 0, m)
         Ab = jax.vmap(lambda a, i: a[i])(self.A, idx)          # (n, batch, d)
         bb = jax.vmap(lambda b, i: b[i])(self.b, idx)          # (n, batch)
-        r = jnp.einsum("nmd,nd->nm", Ab, X) - bb
-        return 2.0 * (m / batch) * jnp.einsum("nmd,nm->nd", Ab, r) + 2.0 * self.lam * X
+        r = jnp.einsum("nmd,nd->nm", Ab, X, precision=HIGHEST) - bb
+        return (2.0 * (m / batch)
+                * jnp.einsum("nmd,nm->nd", Ab, r, precision=HIGHEST)
+                + 2.0 * self.lam * X)
 
     def loss(self, X):
-        r = jnp.einsum("nmd,nd->nm", self.A, X) - self.b
+        r = jnp.einsum("nmd,nd->nm", self.A, X, precision=HIGHEST) - self.b
         return jnp.mean(jnp.sum(r ** 2, -1) + self.lam * jnp.sum(X ** 2, -1))
 
     @property
     def x_star(self) -> jnp.ndarray:
         """Closed form: x* = (sum 2 A_i^T A_i + 2 n lam I)^{-1} sum 2 A_i^T b_i."""
-        H = 2.0 * jnp.einsum("nmd,nme->de", self.A, self.A) + \
-            2.0 * self.n * self.lam * jnp.eye(self.d)
-        g = 2.0 * jnp.einsum("nmd,nm->d", self.A, self.b)
+        H = (2.0 * jnp.einsum("nmd,nme->de", self.A, self.A, precision=HIGHEST)
+             + 2.0 * self.n * self.lam * jnp.eye(self.d))
+        g = 2.0 * jnp.einsum("nmd,nm->d", self.A, self.b, precision=HIGHEST)
         return jnp.linalg.solve(H, g)
 
     @property
     def mu_L(self):
         """Assumption 4 constants: EACH f_i is L-smooth / mu-strongly convex,
         so mu = min_i lambda_min(H_i), L = max_i lambda_max(H_i)."""
-        H = 2.0 * jnp.einsum("nmd,nme->nde", self.A, self.A) + \
-            2.0 * self.lam * jnp.eye(self.d)[None]
+        H = (2.0 * jnp.einsum("nmd,nme->nde", self.A, self.A, precision=HIGHEST)
+             + 2.0 * self.lam * jnp.eye(self.d)[None])
         ev = jnp.linalg.eigvalsh(H)                     # (n, d)
         return float(jnp.min(ev[:, 0])), float(jnp.max(ev[:, -1]))
 
@@ -127,7 +138,7 @@ class LogisticRegression:
         return X.reshape(n, self.feats.shape[2], self.n_classes)
 
     def _loss_one(self, w, feats, labels):
-        logits = feats @ w                                   # (m, c)
+        logits = jnp.matmul(feats, w, precision=HIGHEST)     # (m, c)
         logp = jax.nn.log_softmax(logits)
         nll = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
         return nll + 0.5 * self.lam * jnp.sum(w ** 2)

@@ -89,7 +89,7 @@ import jax.numpy as jnp
 
 from repro.core import faults as faults_mod
 from repro.core import topology as topology_mod
-from repro.core.gossip import (DenseGossip, EncodedNeighborGossip,
+from repro.core.gossip import (HIGHEST, DenseGossip, EncodedNeighborGossip,
                                HierarchicalGossip)
 from repro.core.lead import _at
 from repro.kernels import quantize as _q
@@ -274,7 +274,8 @@ class FlatEngineBase:
 
     @property
     def nb(self) -> int:
-        """nb_logical rounded up to a tile multiple (kernel grid constraint)."""
+        """nb_logical rounded up to a tile multiple (bounded padding; the
+        kernels themselves take any row count)."""
         return -(-self.nb_logical // self.tile_b) * self.tile_b
 
     # -- layout ------------------------------------------------------------
@@ -306,7 +307,8 @@ class FlatEngineBase:
             W = jnp.asarray(self.topology.Ws, buf.dtype)[r]
         else:
             W = jnp.asarray(self.W, buf.dtype)
-        return (W @ buf.reshape(buf.shape[0], -1)).reshape(buf.shape)
+        return jnp.matmul(W, buf.reshape(buf.shape[0], -1),
+                          precision=HIGHEST).reshape(buf.shape)
 
     def mix_round(self, buf: jnp.ndarray, k) -> jnp.ndarray:
         """W_k @ buf through the engine's gossip backend: the step's round
@@ -325,15 +327,6 @@ class FlatEngineBase:
         Shape-derived (not read off the engine's dim) so the same kernels run
         on the trainer's per-leaf buffers, whose nb differs per leaf."""
         return buf.reshape(-1, buf.shape[-1])
-
-    @staticmethod
-    def _tile_for(n_rows: int, cap: int = _q.DEFAULT_TILE_B) -> int:
-        """Largest power-of-two tile <= cap dividing a row count (the Pallas
-        grid constraint for buffers whose nb was not tile-padded)."""
-        t = cap
-        while t > 1 and n_rows % t:
-            t //= 2
-        return t
 
     # -- hyper-parameters ----------------------------------------------------
     @property
@@ -393,8 +386,7 @@ class FlatEngineBase:
             kk = jnp.zeros((), jnp.int32) if k is None else k
             u = self._dither_plane(key, kk, n_rows=buf.shape[0])
             code, scale = _q.encode(self._rows(buf), self._rows(u),
-                                    bits=comp.bits, tile_b=self.tile_b,
-                                    interpret=self.interpret)
+                                    bits=comp.bits, interpret=self.interpret)
             return self.quant_payload(code, scale, comp.bits)
         payload, bits = comp.encode_blocks(key, buf, self.dim,
                                            interpret=self.interpret)
@@ -416,7 +408,7 @@ class FlatEngineBase:
         def decode(pl):
             rows = _q.decode(pl["code"].reshape(-1, self.block),
                              pl["scale"].reshape(-1, 1), bits=bits,
-                             tile_b=self.tile_b, interpret=self.interpret)
+                             interpret=self.interpret)
             return rows.reshape(shape3)
 
         wire = jnp.asarray(self.dim * (bits + 1) + self.nb_logical * 32,

@@ -71,7 +71,6 @@ import jax.numpy as jnp
 from repro.core.engines.base import FlatEngineBase, _is_fused_quantizer
 from repro.core.lead import LEADHyper, Schedule, _at
 from repro.kernels import lead_update as _lu
-from repro.kernels import quantize as _q
 
 
 class FlatLEADState(NamedTuple):
@@ -157,8 +156,7 @@ class FlatLEADEngine(FlatEngineBase):
                 self._rows(s.x), self._rows(gb), self._rows(s.d),
                 self._rows(s.h),
                 self._rows(self._dither_plane(key, s.k)),
-                hy["eta"], bits=comp.bits, tile_b=self.tile_b,
-                interpret=self.interpret)
+                hy["eta"], bits=comp.bits, interpret=self.interpret)
             payload, decode, bits = self.quant_payload(code, scale, comp.bits)
             return payload, decode, bits, None
         return super().encode_stage(s, gb, key, hy)
@@ -166,8 +164,9 @@ class FlatLEADEngine(FlatEngineBase):
     def apply_stage(self, s: FlatLEADState, gb, qh, wqh, hy, ctx=None):
         """Post-communication fused H / H_w / D / X update (lines 5-7) plus
         the exact in-step comp_err ||Qh - (Y-H)|| / ||Y||.  Shape-derived
-        rows and tile so the same kernel call serves the engine's own padded
-        buffers and the trainer's per-leaf blocks."""
+        rows (the kernel fits its tile to them) so the same kernel call
+        serves the engine's own padded buffers and the trainer's per-leaf
+        blocks."""
         if self._bank:
             # Time-varying graphs break the incremental invariant
             # hw == W h that static LEAD maintains for free (hw would
@@ -180,13 +179,11 @@ class FlatLEADEngine(FlatEngineBase):
             # (receivers hold replicas in a real deployment), so this mix
             # is clean even on the faulted path.
             wqh = self.mix_round(s.h, s.k) + wqh - s.hw
-        rows = self._rows(s.x)
-        tile = self._tile_for(rows.shape[0])
         xo, do, ho, hwo = _lu.lead_update(
-            rows, self._rows(gb), self._rows(s.d),
+            self._rows(s.x), self._rows(gb), self._rows(s.d),
             self._rows(s.h), self._rows(s.hw), self._rows(qh),
             self._rows(wqh), hy["eta"], hy["gamma"], hy["alpha"],
-            tile_b=tile, interpret=self.interpret)
+            interpret=self.interpret)
         shape3 = s.x.shape
         new = FlatLEADState(x=xo.reshape(shape3), d=do.reshape(shape3),
                             h=ho.reshape(shape3), hw=hwo.reshape(shape3),

@@ -44,8 +44,13 @@ from typing import Any, Callable, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from repro.utils.tree import Pytree, tree_map
+
+# Every dense mix by W runs at full f32 precision.  A TPU's default f32
+# matmul is one bf16 pass, which rounds the ring's weights 1/3 to 0.333984:
+# rows then sum to 1.002, W stops being doubly stochastic, and LEAD's dual
+# no longer sums to zero, which moves its fixed point off x*.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,7 +86,8 @@ class DenseGossip:
         W = jnp.asarray(self.W)
 
         def one(x):
-            return jnp.tensordot(W.astype(x.dtype), x, axes=([1], [0]))
+            return jnp.tensordot(W.astype(x.dtype), x, axes=([1], [0]),
+                                 precision=HIGHEST)
 
         return tree_map(one, tree)
 
@@ -113,7 +119,8 @@ class DenseGossip:
         shape = (-1,) + (1,) * (x.ndim - 1)
 
         def matmul(M, b):
-            return (M @ b.reshape(n, -1)).reshape(b.shape)
+            return jnp.matmul(M, b.reshape(n, -1),
+                              precision=HIGHEST).reshape(b.shape)
 
         if cache is None:
             Wr = faults_mod.renormalize_dense(W, mask)
@@ -361,11 +368,11 @@ class RingGossip:
         return self.axes if len(self.axes) > 1 else self.axes[0]
 
     def n_agents(self) -> jnp.ndarray:
-        return axis_size(self.axis_name)
+        return jax.lax.axis_size(self.axis_name)
 
     def shift(self, tree: Pytree, direction: int) -> Pytree:
         """ppermute every leaf by +1/-1 around the ring (wire traffic!)."""
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         fwd, bwd = _ring_perms(n)
         perm = fwd if direction > 0 else bwd
 

@@ -88,7 +88,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import faults as faults_mod
 from repro.core import topology
 from repro.core.compression import QuantizePNorm
@@ -493,7 +492,7 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
     axis_name = (prof.agent_axes if len(prof.agent_axes) > 1
                  else prof.agent_axes[0])
     spec = P(prof.agent_axes)            # leading agent axis; rest replicated
-    smap = functools.partial(compat.shard_map, mesh=mesh,
+    smap = functools.partial(jax.shard_map, mesh=mesh,
                              axis_names=set(mesh.axis_names), check_vma=False)
 
     def _pperm(tree, pairs):
@@ -507,7 +506,7 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
         ppermute pair numbering)."""
         idx = jax.lax.axis_index(prof.agent_axes[0])
         for a in prof.agent_axes[1:]:
-            idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
 
     # -- gradients ----------------------------------------------------------
@@ -785,9 +784,23 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
                           tuple(wq for _, wq in q_wqs[i:i + n_wires]))
                          for i in range(0, len(q_wqs), n_wires)]
 
-            new_states = [eng.apply_stage(s_leaf, gb, q, wq, hy, ctx)[0]
-                          for s_leaf, gb, (q, wq), ctx
-                          in zip(states, gbs, q_wqs, ctxs)]
+            def apply_leaves(sts, gs, qws, cs, h):
+                return [eng.apply_stage(s_leaf, gb, q, wq, h, ctx)[0]
+                        for s_leaf, gb, (q, wq), ctx in zip(sts, gs, qws, cs)]
+
+            if is_bank:
+                # a bank's apply stage re-mixes H with the step's graph, so
+                # it needs the whole agent axis
+                new_states = apply_leaves(states, gbs, q_wqs, ctxs, hy)
+            else:
+                # every static-graph apply stage is per-agent algebra: run
+                # it on each device's own agents, where a Pallas kernel
+                # (which XLA cannot partition) sees a local buffer
+                args = (states, gbs, q_wqs, ctxs, hy)
+                specs = tree_map(lambda l: spec if jnp.ndim(l) else P(),
+                                 args)
+                new_states = smap(apply_leaves, in_specs=specs,
+                                  out_specs=specs[0])(*args)
             new_x, new_algo = _unblock(new_states)
             if hier:
                 # project the FULL state back to block-constant — each node

@@ -30,8 +30,11 @@ Flat block layout contract
 All kernels operate on the blockified layout produced by ops._to_blocks:
 a logical f32 vector of length d is zero-padded and reshaped to
 ``(nb, block)`` with ``block = 512`` (the paper's quantization block,
-4 x 128 TPU lanes) and ``nb`` a multiple of the grid tile ``tile_b``.
-Rows are independent quantization blocks, so batched callers (the flat
+4 x 128 TPU lanes) and any ``nb``: each kernel takes ``quantize.fit_tile``
+rows per grid step — the whole array, or 256 rows with a ragged last
+block — because Mosaic refuses a block whose row count is neither a
+multiple of 8 nor the whole array's.  Rows are independent quantization
+blocks, so batched callers (the flat
 engine family in core/engines/ — LEAD plus the flat twins of every paper
 baseline) may stack agents along the row axis — ``(n_agents * nb, block)``
 — and make a single kernel call.  Zero rows are a fixed point of every

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.dispatch import resolve_backend
-from repro.kernels.quantize import DEFAULT_TILE_B
+from repro.kernels.quantize import DEFAULT_TILE_B, fit_tile
 
 
 def _lead_update_kernel(eta_ref, gamma_ref, alpha_ref,
@@ -48,7 +48,8 @@ def _lead_update_kernel(eta_ref, gamma_ref, alpha_ref,
 
 def lead_update(x, g, d, h, hw, qh, wqh, eta, gamma, alpha, *,
                 tile_b: int = DEFAULT_TILE_B, interpret: Optional[bool] = None):
-    """All tensors (nb, block) f32; scalars broadcastable to (1, 1) f32.
+    """All tensors (nb, block) f32, any nb (tile_b caps the rows per grid
+    step; see quantize.fit_tile); scalars broadcastable to (1, 1) f32.
 
     Returns (x_new, d_new, h_new, hw_new)."""
     backend = resolve_backend(interpret)
@@ -59,8 +60,8 @@ def lead_update(x, g, d, h, hw, qh, wqh, eta, gamma, alpha, *,
                                          jnp.asarray(gamma, jnp.float32),
                                          jnp.asarray(alpha, jnp.float32)))
     nb, block = x.shape
-    assert nb % tile_b == 0
-    grid = (nb // tile_b,)
+    tile_b = fit_tile(nb, tile_b)
+    grid = (pl.cdiv(nb, tile_b),)
     scal = lambda v: jnp.asarray(v, jnp.float32).reshape(1, 1)
     tile = pl.BlockSpec((tile_b, block), lambda i: (i, 0))
     smem = pl.BlockSpec((1, 1), lambda i: (0, 0))
@@ -98,8 +99,8 @@ def lead_diff_encode(x, g, d, h, u, eta, *, bits: int = 2,
         return ref.lead_diff_encode_ref(x, g, d, h, u,
                                         jnp.asarray(eta, jnp.float32), bits)
     nb, block = x.shape
-    assert nb % tile_b == 0
-    grid = (nb // tile_b,)
+    tile_b = fit_tile(nb, tile_b)
+    grid = (pl.cdiv(nb, tile_b),)
     tile = pl.BlockSpec((tile_b, block), lambda i: (i, 0))
     smem = pl.BlockSpec((1, 1), lambda i: (0, 0))
     return pl.pallas_call(

@@ -36,12 +36,16 @@ def _from_blocks(blocks: jnp.ndarray, n: int, shape, dtype):
 
 
 def _pick_tile(n_elements: int, block: int, tile_b: int) -> int:
-    """Shrink the tile for small inputs so padding stays bounded."""
+    """Tile of the padded (nb, block) layout: tile_b halved (in multiples
+    of 8) until it fits in nb, so padding stays bounded.  It obeys the
+    kernels' tile rule (quantize.fit_tile): a multiple of 8 rows, or —
+    under 8 blocks — the next power of two >= nb, which is then the whole
+    padded row count."""
     nb = max(1, -(-n_elements // block))
-    t = tile_b
-    while t > 1 and t > nb:
-        t //= 2
-    return t
+    t = max(8, tile_b - tile_b % 8)
+    while t > 8 and t > nb:
+        t = max(8, t // 16 * 8)
+    return t if t <= nb else 1 << (nb - 1).bit_length()
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "tile_b", "interpret"))

@@ -33,6 +33,21 @@ DEFAULT_BLOCK = 512     # paper's quantization block
 DEFAULT_TILE_B = 256    # blocks per grid step: 256*512*4B*3 buffers ~ 1.5 MB VMEM
 
 
+def fit_tile(n_rows: int, cap: int = DEFAULT_TILE_B) -> int:
+    """Rows per grid step for an (n_rows, block) operand — the one tile
+    rule of every kernel in this package.
+
+    Mosaic accepts a block whose row count is a multiple of 8 or equals the
+    whole array's.  So the tile is the whole row count when it fits under
+    ``cap``, and ``cap`` rounded down to a multiple of 8 otherwise.  The
+    grid is ``pl.cdiv(n_rows, tile)``: a row count the tile does not divide
+    (granite's 49155 x 2048 embedding is 196,620 rows) ends in a partial
+    block, which Pallas pads on read and masks on write.  Every kernel here
+    is row-independent, so the padded rows never reach a valid one."""
+    cap = max(8, cap - cap % 8)
+    return n_rows if n_rows <= cap else cap
+
+
 def _encode_kernel(x_ref, u_ref, code_ref, scale_ref, *, bits: int):
     x = x_ref[...]
     u = u_ref[...]
@@ -51,7 +66,8 @@ def _decode_kernel(code_ref, scale_ref, out_ref, *, bits: int):
 
 def encode(x: jnp.ndarray, u: jnp.ndarray, *, bits: int = 2,
            tile_b: int = DEFAULT_TILE_B, interpret: Optional[bool] = None):
-    """x, u: (nb, block) f32 with nb % tile_b == 0 (ops.py pads).
+    """x, u: (nb, block) f32, any nb (``tile_b`` caps the rows per grid
+    step; see fit_tile).
 
     Returns (code int8 (nb, block), scale f32 (nb, 1))."""
     assert 1 <= bits <= 7, "int8 code container supports bits in [1, 7]"
@@ -60,8 +76,8 @@ def encode(x: jnp.ndarray, u: jnp.ndarray, *, bits: int = 2,
         from repro.kernels import ref
         return ref.quantize_encode_ref(x, u, bits)
     nb, block = x.shape
-    assert nb % tile_b == 0, f"nb={nb} must be a multiple of tile_b={tile_b}"
-    grid = (nb // tile_b,)
+    tile_b = fit_tile(nb, tile_b)
+    grid = (pl.cdiv(nb, tile_b),)
     return pl.pallas_call(
         functools.partial(_encode_kernel, bits=bits),
         grid=grid,
@@ -89,8 +105,8 @@ def decode(code: jnp.ndarray, scale: jnp.ndarray, *, bits: int = 2,
         from repro.kernels import ref
         return ref.quantize_decode_ref(code, scale, bits)
     nb, block = code.shape
-    assert nb % tile_b == 0
-    grid = (nb // tile_b,)
+    tile_b = fit_tile(nb, tile_b)
+    grid = (pl.cdiv(nb, tile_b),)
     return pl.pallas_call(
         functools.partial(_decode_kernel, bits=bits),
         grid=grid,

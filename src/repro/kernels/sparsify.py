@@ -26,18 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.dispatch import resolve_backend
-from repro.kernels.quantize import DEFAULT_TILE_B
-
-
-def _fit_tile(nb: int, tile_b: int) -> int:
-    """Largest power-of-two tile <= tile_b that divides nb (>= 1).  Callers
-    outside the engine (dist trainer, tests) hand arbitrary row counts; the
-    engine's own buffers are already tile multiples so this is a no-op
-    there."""
-    t = min(tile_b, nb)
-    while t > 1 and nb % t:
-        t //= 2
-    return max(t, 1)
+from repro.kernels.quantize import DEFAULT_TILE_B, fit_tile
 
 
 def _randk_kernel(x_ref, u_ref, out_ref, *, ratio: float, scale: float):
@@ -49,19 +38,20 @@ def _randk_kernel(x_ref, u_ref, out_ref, *, ratio: float, scale: float):
 def randk_encode(x: jnp.ndarray, u: jnp.ndarray, *, ratio: float,
                  rescale: bool = True, tile_b: int = DEFAULT_TILE_B,
                  interpret: Optional[bool] = None) -> jnp.ndarray:
-    """x, u: (nb, block) f32 with nb % tile_b == 0.  Returns the kept-value
-    plane: x * (1/ratio if rescale else 1) where u < ratio, else 0."""
+    """x, u: (nb, block) f32, any nb (see quantize.fit_tile).  Returns the
+    kept-value plane: x * (1/ratio if rescale else 1) where u < ratio,
+    else 0."""
     scale = (1.0 / ratio) if rescale else 1.0
     backend = resolve_backend(interpret)
     if backend == "jnp":
         from repro.kernels import ref
         return ref.randk_encode_ref(x, u, ratio, scale)
     nb, block = x.shape
-    tile_b = _fit_tile(nb, tile_b)
+    tile_b = fit_tile(nb, tile_b)
     tile = pl.BlockSpec((tile_b, block), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_randk_kernel, ratio=ratio, scale=scale),
-        grid=(nb // tile_b,),
+        grid=(pl.cdiv(nb, tile_b),),
         in_specs=[tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
@@ -82,11 +72,11 @@ def mask_apply(x: jnp.ndarray, mask: jnp.ndarray, *,
         from repro.kernels import ref
         return ref.mask_apply_ref(x, mask)
     nb, block = x.shape
-    tile_b = _fit_tile(nb, tile_b)
+    tile_b = fit_tile(nb, tile_b)
     tile = pl.BlockSpec((tile_b, block), lambda i: (i, 0))
     return pl.pallas_call(
         _mask_apply_kernel,
-        grid=(nb // tile_b,),
+        grid=(pl.cdiv(nb, tile_b),),
         in_specs=[tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),

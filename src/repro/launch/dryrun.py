@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import get_config, list_archs
 from repro.data.synthetic import LMStreamConfig
@@ -77,7 +76,7 @@ def build_train_lowering(arch: str, mesh, algorithm: str = "lead",
     step = make_train_step(cfg, mesh, prof, dc)
     key_sds = jax.ShapeDtypeStruct((2,), jnp.uint32)
     jitted = jax.jit(step, in_shardings=(st_shard, bshard, None))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(state_sds, batch_sds, key_sds)
     return lowered, cfg
 
@@ -95,7 +94,7 @@ def build_serve_lowering(arch: str, mesh, shape_name: str, cfg_override=None):
         fn, sds, shardings, cfg2 = serve_mod.make_decode(cfg, mesh, prof, shape)
         order = ["params", "token", "cache"]
     jitted = jax.jit(fn, in_shardings=tuple(shardings[k] for k in order))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*(sds[k] for k in order))
     return lowered, cfg2
 

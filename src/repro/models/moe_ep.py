@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 
 
 def _slots(ids, n_bins, cap_slots):
@@ -50,8 +49,8 @@ def _moe_ep_body(x_loc, router, wg, wu, wd, *, top_k, cap, ep_axis, tp_axis,
     router (d_loc, E)         wg/wu (E_loc, d, f_loc)   wd (E_loc, f_loc, d)
     """
     B, S, d_loc = x_loc.shape
-    nsh = axis_size(ep_axis)
-    ntp = axis_size(tp_axis)
+    nsh = jax.lax.axis_size(ep_axis)
+    ntp = jax.lax.axis_size(tp_axis)
     E_loc = wg.shape[0]
 
     # 1. gather expert weights over TP once (amortized over all chunks).
@@ -140,7 +139,7 @@ def moe_apply_ep(p, x, *, top_k, capacity_factor=1.25, ep_axis="data",
     body = functools.partial(_moe_ep_body, top_k=top_k, cap=capacity_factor,
                              ep_axis=ep_axis, tp_axis=tp_axis,
                              seq_chunk=seq_chunk)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body,
         in_specs=(P(ep_axis, None, tp_axis), P(tp_axis, None),
                   P(ep_axis, None, tp_axis), P(ep_axis, None, tp_axis),

@@ -61,15 +61,6 @@ class KVQuantSpec:
         return elems_per_page * (self.bits + 1) + nb * 32
 
 
-def _tile_for(nb_total: int) -> int:
-    """tile_b that divides the row count (Pallas grid constraint; the jnp
-    reference backend ignores it)."""
-    t = min(_q.DEFAULT_TILE_B, nb_total)
-    while nb_total % t:
-        t -= 1
-    return t
-
-
 def encode_rows(x: jnp.ndarray, spec: KVQuantSpec,
                 interpret: Optional[bool] = None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -84,8 +75,7 @@ def encode_rows(x: jnp.ndarray, spec: KVQuantSpec,
     assert nb * spec.block == elems, (elems, spec.block)
     xb = x.astype(jnp.float32).reshape(R * nb, spec.block)
     u = jnp.full(xb.shape, 0.5, jnp.float32)
-    code, scale = _q.encode(xb, u, bits=spec.bits,
-                            tile_b=_tile_for(R * nb), interpret=interpret)
+    code, scale = _q.encode(xb, u, bits=spec.bits, interpret=interpret)
     return code.reshape(R, nb, spec.block), scale.reshape(R, nb, 1)
 
 
@@ -100,5 +90,5 @@ def decode_rows(code: jnp.ndarray, scale: jnp.ndarray, spec: KVQuantSpec,
     nb = code.shape[-2]
     vals = _q.decode(code.reshape(R * nb, spec.block),
                      scale.reshape(R * nb, 1), bits=spec.bits,
-                     tile_b=_tile_for(R * nb), interpret=interpret)
+                     interpret=interpret)
     return vals.reshape(*lead, *page_shape).astype(dtype)

@@ -44,9 +44,8 @@ jax.config.update("jax_threefry_partitionable", True)
 
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.compat import AxisType, make_mesh, set_mesh
 from repro.configs.registry import get_config
 from repro.data.synthetic import LMStreamConfig, lm_batch
 from repro.dist import sharding as shr
@@ -60,7 +59,7 @@ from repro.utils.tree import tree_map
 
 def _setup(algorithm, mesh_shape=(4, 2), axes=("data", "model"),
            n_agents=4, **dc_kwargs):
-    mesh = make_mesh(mesh_shape, axes,
+    mesh = jax.make_mesh(mesh_shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
     cfg = get_config("granite-3-2b").reduced()
     prof = shr.make_profile(cfg, mesh.axis_names)
@@ -69,7 +68,7 @@ def _setup(algorithm, mesh_shape=(4, 2), axes=("data", "model"),
     key = jax.random.PRNGKey(0)
     state_sds = jax.eval_shape(lambda k: init_train_state(cfg, mesh, prof, dc, k), key)
     shardings = state_shardings(cfg, mesh, prof, state_sds)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.jit(lambda k: init_train_state(cfg, mesh, prof, dc, k),
                         out_shardings=shardings)(key)
     ds = LMStreamConfig(vocab=cfg.vocab, seq_len=32, batch_per_agent=2,
@@ -95,7 +94,7 @@ def case_nids_equivalence():
     x_ref = jax.device_get(state.params)
     d_ref = jax.device_get(state.algo["d"])
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(3):
             g = jax.device_get(grad_fn(jax.device_put(x_ref), batch))
             y = tree_map(lambda xl, gl, dl: xl - eta * (gl + dl), x_ref, g, d_ref)
@@ -127,7 +126,7 @@ def case_lead_train():
             cnt += l.size
         return tot / cnt
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l0 = float(jnp.mean(loss_fn_v(state.params, batch)))
         c0 = consensus(state.params)
         for i in range(20):
@@ -145,7 +144,7 @@ def case_lead_train():
 
 
 def case_dryrun_multipod():
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
                          axis_types=(AxisType.Auto,) * 3)
     cfg = get_config("granite-moe-1b-a400m").reduced()
     prof = shr.make_profile(cfg, mesh.axis_names)
@@ -160,7 +159,7 @@ def case_dryrun_multipod():
     bshard = {k: NamedSharding(mesh, shr.train_batch_spec(prof))
               for k in batch_sds}
     step = make_train_step(cfg, mesh, prof, dc)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=(shardings, bshard, None)).lower(
             state_sds, batch_sds, jax.ShapeDtypeStruct((2,), jnp.uint32))
         compiled = lowered.compile()
@@ -177,7 +176,7 @@ def case_dryrun_multipod():
     from repro.dist import serve as serve_mod
     shape = InputShape("decode_small", 128, 8, "decode")
     fn, sds, shardings2, cfg2 = serve_mod.make_decode(cfg, mesh, prof, shape)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=(
             shardings2["params"], shardings2["token"], shardings2["cache"]),
         ).lower(sds["params"], sds["token"], sds["cache"])
@@ -189,7 +188,7 @@ def case_perf_variants():
     """seq_parallel + wire_pack + microbatches + bf16: loss decreases and
     the dual-sum invariant holds on the optimized path too."""
     from repro.dist.trainer import DistConfig as DC
-    mesh = make_mesh((4, 2), ("data", "model"),
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("granite-3-2b").reduced()
     prof = shr.make_profile(cfg, mesh.axis_names)
@@ -199,7 +198,7 @@ def case_perf_variants():
     key = jax.random.PRNGKey(0)
     state_sds = jax.eval_shape(lambda k: init_train_state(cfg, mesh, prof, dc, k), key)
     shardings = state_shardings(cfg, mesh, prof, state_sds)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.jit(lambda k: init_train_state(cfg, mesh, prof, dc, k),
                         out_shardings=shardings)(key)
         step = jax.jit(make_train_step(cfg, mesh, prof, dc))
@@ -261,7 +260,7 @@ def case_registry_equivalence():
     total = n_bad = 0
     scale = 1.0
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(3):
             # re-sync: one-step comparison from the common reference state
             state = TrainState(params=jax.device_put(x),
@@ -340,7 +339,7 @@ def case_baselines_multihost():
     state = init_train_state(cfg, mesh, prof, dc, jax.random.PRNGKey(0))
     step = jax.jit(make_train_step(cfg, mesh, prof, dc))
     loss_fn_v = jax.jit(jax.vmap(lambda p, b: tfm.loss_fn(p, cfg, b)[0]))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l0 = float(jnp.mean(loss_fn_v(state.params, batch)))
         metrics = None
         for i in range(12):
@@ -359,7 +358,7 @@ def case_baselines_multihost():
     for name in ("deepsqueeze", "extra"):
         mesh, cfg, prof, dc, state, batch, key, ds = _setup(name)
         step = jax.jit(make_train_step(cfg, mesh, prof, dc))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state, m = step(state, batch, key)
             state, m = step(state, batch, jax.random.fold_in(key, 1))
         finite = all(bool(jnp.all(jnp.isfinite(l)))
@@ -384,7 +383,7 @@ def case_baselines_multihost():
     eta = engine_of(dc, 2).eta
     x_ref = jax.device_get(state.params)
     d_ref = jax.device_get(state.algo["d"])
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(2):
             g = jax.device_get(grad_fn(jax.device_put(x_ref), batch))
             y = tree_map(lambda xl, gl, dl: xl - eta * gl - eta * dl,
@@ -421,7 +420,7 @@ def case_cgt_train():
     state = init_train_state(cfg, mesh, prof, dc, jax.random.PRNGKey(0))
     step = jax.jit(make_train_step(cfg, mesh, prof, dc))
     loss_fn_v = jax.jit(jax.vmap(lambda p, b: tfm.loss_fn(p, cfg, b)[0]))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l0 = float(jnp.mean(loss_fn_v(state.params, batch)))
         metrics = None
         for i in range(12):
@@ -473,7 +472,7 @@ def case_faulted_checkpoint_resume():
                               NamedSharding(mesh, shr.train_batch_spec(prof)))
 
     dropped = 0.0
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l0 = float(jnp.mean(loss_fn_v(state0.params, batch)))
         # continuous 8-step run
         sa = state0
@@ -530,7 +529,7 @@ def case_topology_multihost():
         eta = engine_of(dc, 4).eta
         x_ref = jax.device_get(state.params)
         d_ref = jax.device_get(state.algo["d"])
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for i in range(3):
                 g = jax.device_get(grad_fn(jax.device_put(x_ref), batch))
                 y = tree_map(lambda xl, gl, dl: xl - eta * gl - eta * dl,
@@ -557,7 +556,7 @@ def case_topology_multihost():
     state = init_train_state(cfg, mesh, prof, dc, jax.random.PRNGKey(0))
     step = jax.jit(make_train_step(cfg, mesh, prof, dc))
     loss_fn_v = jax.jit(jax.vmap(lambda p, b: tfm.loss_fn(p, cfg, b)[0]))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l0 = float(jnp.mean(loss_fn_v(state.params, batch)))
         for i in range(10):
             b = jax.device_put(lm_batch(ds, i),
@@ -591,7 +590,7 @@ def case_timevarying_multihost():
     grad_fn = jax.vmap(jax.grad(lambda p, b: tfm.loss_fn(p, cfg, b)[0]))
     eta = engine_of(dc, 4).eta
     x_ref = jax.device_get(state.params)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(4):
             g = jax.device_get(grad_fn(jax.device_put(x_ref), batch))
             W = Ws[i % bank.period]
@@ -615,7 +614,7 @@ def case_timevarying_multihost():
     mesh, cfg, prof, dc, state, batch, key, ds = _setup("lead", topology=bank)
     step = jax.jit(make_train_step(cfg, mesh, prof, dc))
     loss_fn_v = jax.jit(jax.vmap(lambda p, b: tfm.loss_fn(p, cfg, b)[0]))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l0 = float(jnp.mean(loss_fn_v(state.params, batch)))
         for i in range(8):
             b = jax.device_put(lm_batch(ds, i),
@@ -637,7 +636,7 @@ def case_timevarying_multihost():
         "lead", topology=bank, faults=fm)
     step = jax.jit(make_train_step(cfg, mesh, prof, dc))
     dropped = 0.0
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(6):
             state, m = step(state, batch, jax.random.fold_in(key, i))
             d_i = float(m["dropped_links"])

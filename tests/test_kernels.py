@@ -106,7 +106,7 @@ def test_mask_apply_matches_ref(key):
 def test_sparsify_fits_tile_to_arbitrary_row_counts(nb, key):
     """Regression: row counts that don't divide the default tile must not
     crash the non-jnp backends (callers outside the engine hand arbitrary
-    nb; the tile auto-shrinks to a divisor)."""
+    nb; quantize.fit_tile takes the whole array or a ragged last block)."""
     from repro.kernels import sparsify
     block = 512
     x = jax.random.normal(key, (nb, block))
@@ -120,6 +120,86 @@ def test_sparsify_fits_tile_to_arbitrary_row_counts(nb, key):
     got2 = sparsify.mask_apply(x, m, interpret=True)
     np.testing.assert_array_equal(np.asarray(got2),
                                   np.asarray(ref.mask_apply_ref(x, m)))
+
+
+def _old_pick_tile(n_elements, block, tile_b):
+    """The power-of-two tile ladder _pick_tile used before the TPU tile
+    rule; the padded layouts it produced must not change."""
+    nb = max(1, -(-n_elements // block))
+    t = tile_b
+    while t > 1 and t > nb:
+        t //= 2
+    return t
+
+
+@pytest.mark.parametrize("picker", ["fit_tile", "pick_tile"])
+def test_tile_rule_multiple_of_8_or_whole(picker):
+    """Mosaic takes a block's row count only as a multiple of 8 or as the
+    whole array's: both tile pickers must return one of the two (the old
+    power-of-two ladders handed 2 or 4 rows to multi-tile arrays, which the
+    TPU compiler refuses)."""
+    from repro.kernels.quantize import fit_tile
+    for n in list(range(1, 600)) + [196_620, 196_608, 262_144, 5 * 8]:
+        for cap in (4, 8, 100, 256):
+            if picker == "fit_tile":
+                t, rows = fit_tile(n, cap), n
+            else:
+                t = _pick_tile(n * 512, 512, cap)
+                rows = -(-n // t) * t          # the padded row count
+            assert t % 8 == 0 or t == rows, (picker, n, cap, t, rows)
+            assert t <= max(cap, 8) or t == rows
+
+
+def test_pick_tile_keeps_padded_layout():
+    """The rule changed which tile is handed to the kernels, not the
+    engine's padded (nb, block) layout: nb rounds up exactly as before."""
+    for n in range(1, 2000):
+        new, old = _pick_tile(n * 512, 512, 256), _old_pick_tile(n * 512, 512, 256)
+        assert -(-n // new) * new == -(-n // old) * old, (n, new, old)
+
+
+def _pad_call(fn, rows, tile, *arrs):
+    """Explicit pad-to-a-tile-multiple, call, slice back: what the ragged
+    grid does implicitly."""
+    pad = (-rows) % tile
+    outs = fn(*[jnp.pad(a, ((0, pad), (0, 0))) for a in arrs])
+    single = not isinstance(outs, (tuple, list))
+    outs = [outs] if single else outs
+    outs = [o[:rows] for o in outs]
+    return outs[0] if single else outs
+
+
+@pytest.mark.parametrize("rows", [12, 300, 1001])
+def test_ragged_lead_update_matches_unpadded(rows, key):
+    """A row count the tile does not divide runs as a ragged grid; every
+    output row equals the single-block (unpadded) call and the explicit
+    pad-and-slice call bit for bit."""
+    from repro.kernels import lead_update as lu
+    arrs = [jax.random.normal(jax.random.fold_in(key, i), (rows, 512))
+            for i in range(7)]
+    whole = lu.lead_update(*arrs, 0.1, 1.0, 0.5, tile_b=rows + 8,
+                           interpret=True)
+    ragged = lu.lead_update(*arrs, 0.1, 1.0, 0.5, tile_b=8, interpret=True)
+    padded = _pad_call(lambda *a: lu.lead_update(*a, 0.1, 1.0, 0.5, tile_b=8,
+                                                 interpret=True),
+                       rows, 8, *arrs)
+    for w, r, p in zip(whole, ragged, padded):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(w))
+        np.testing.assert_array_equal(np.asarray(p), np.asarray(w))
+
+
+@pytest.mark.parametrize("rows", [12, 300, 1001])
+def test_ragged_randk_encode_matches_unpadded(rows, key):
+    from repro.kernels import sparsify
+    x = jax.random.normal(key, (rows, 512))
+    u = jax.random.uniform(jax.random.fold_in(key, 1), (rows, 512))
+    whole = sparsify.randk_encode(x, u, ratio=0.3, tile_b=rows + 8,
+                                  interpret=True)
+    ragged = sparsify.randk_encode(x, u, ratio=0.3, tile_b=8, interpret=True)
+    padded = _pad_call(lambda a, b: sparsify.randk_encode(
+        a, b, ratio=0.3, tile_b=8, interpret=True), rows, 8, x, u)
+    np.testing.assert_array_equal(np.asarray(ragged), np.asarray(whole))
+    np.testing.assert_array_equal(np.asarray(padded), np.asarray(whole))
 
 
 @settings(max_examples=15, deadline=None)

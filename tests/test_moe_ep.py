@@ -11,7 +11,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.models import moe as moe_mod
 from repro.models import moe_ep
 
@@ -21,8 +21,8 @@ p = moe_mod.moe_init(key, d, dff, E)
 x = jax.random.normal(key, (4, 16, d))
 ref, _ = moe_mod.moe_apply(p, x, top_k=k, capacity_factor=8.0)
 for shape in ((4, 1), (2, 4), (4, 2)):
-    mesh = make_mesh(shape, ("data", "model"), axis_types=(AxisType.Auto,)*2)
-    with set_mesh(mesh):
+    mesh = jax.make_mesh(shape, ("data", "model"), axis_types=(AxisType.Auto,)*2)
+    with jax.set_mesh(mesh):
         px = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         pp = {kk: jax.device_put(v, NamedSharding(mesh, P())) for kk, v in p.items()}
         for chunk in (0, 8):
