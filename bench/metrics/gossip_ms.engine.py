@@ -1,0 +1,6 @@
+"""gossip_ms.engine: gossip_ms.train's reading (the stage.gossip scope,
+which the flat engines share with the trainer) in the engine cells, where
+it moves engine_steps_per_s."""
+from bench import readers
+
+read = readers.same_as("gossip_ms.train")

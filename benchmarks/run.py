@@ -7,7 +7,6 @@ Prints ``name,us_per_call,derived`` CSV rows.  Modules:
     bench_compression   Fig 5/6 (p-norm quantization error, methods) + kernels
     bench_sensitivity   Fig 7  (alpha x gamma robustness grid)
     bench_nn            Fig 4 proxy (non-convex LM, hom/het)
-    bench_roofline      §Roofline aggregation from reports/dryrun
     bench_lead_step     flat-buffer engine vs pytree path step latency
     bench_baselines     flat engine family vs tree baselines (Fig 2-4 sweep)
     bench_gossip        dense vs neighbor-exchange mixing at n in {8,32,128}
@@ -28,8 +27,8 @@ import traceback
 
 from benchmarks import (bench_baselines, bench_compression, bench_faults,
                         bench_gossip, bench_lead_step, bench_linreg,
-                        bench_logreg, bench_nn, bench_roofline,
-                        bench_sensitivity, bench_serve, bench_theory)
+                        bench_logreg, bench_nn, bench_sensitivity,
+                        bench_serve, bench_theory)
 from benchmarks.common import drain_rows, write_json
 from repro.utils.compile_cache import use_compile_cache
 
@@ -40,7 +39,6 @@ ALL = {
     "sensitivity": bench_sensitivity.main,
     "nn": bench_nn.main,
     "theory": bench_theory.main,
-    "roofline": bench_roofline.main,
     "lead_step": bench_lead_step.main,
     "baselines": bench_baselines.main,
     "gossip": bench_gossip.main,

@@ -86,8 +86,6 @@ class ModelConfig:
     # sharding profile: "default" (agents over pod x data, TP over model) or
     # "xxl" (agents over pod only; experts EP-sharded over data).
     sharding_profile: str = "default"
-    # with "xxl": additionally FSDP-shard dense weights over (data, model)
-    dense_fsdp: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:

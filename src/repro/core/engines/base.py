@@ -351,16 +351,20 @@ class FlatEngineBase:
         dither).  "fast": one counter-hash pass seeded from (key, iteration
         counter k)."""
         rows = self.n if n_rows is None else n_rows
-        if self.dither == "fast":
-            raw = (key if jnp.issubdtype(key.dtype, jnp.integer)
-                   else jax.random.key_data(key))
-            seed = jnp.bitwise_xor(jnp.ravel(raw)[-1].astype(jnp.uint32),
-                                   k.astype(jnp.uint32))
-            return fast_uniform((rows, self.nb, self.block), seed)
-        keys = jax.random.split(key, rows)
-        shape = (self.nb_logical, self.block)
-        u = jax.vmap(lambda kk: jax.random.uniform(kk, shape, jnp.float32))(keys)
-        return jnp.pad(u, ((0, 0), (0, self.nb - self.nb_logical), (0, 0)))
+        with jax.named_scope("stage.dither"):
+            if self.dither == "fast":
+                raw = (key if jnp.issubdtype(key.dtype, jnp.integer)
+                       else jax.random.key_data(key))
+                seed = jnp.bitwise_xor(
+                    jnp.ravel(raw)[-1].astype(jnp.uint32),
+                    k.astype(jnp.uint32))
+                return fast_uniform((rows, self.nb, self.block), seed)
+            keys = jax.random.split(key, rows)
+            shape = (self.nb_logical, self.block)
+            u = jax.vmap(lambda kk: jax.random.uniform(kk, shape,
+                                                       jnp.float32))(keys)
+            return jnp.pad(u, ((0, 0), (0, self.nb - self.nb_logical),
+                               (0, 0)))
 
     # -- wire --------------------------------------------------------------
     def encode_payload(self, key: jax.Array, buf: jnp.ndarray, k=None):
@@ -650,15 +654,25 @@ class FlatEngineBase:
         apply) fires only at ``k % tau == 0`` behind a lax.cond; the other
         steps run ``local_stage`` (zero bits, comp_err 0).  tau == 1 takes
         the branch-free path — its jaxpr is exactly the pre-interval
-        substrate's."""
+        substrate's.
+
+        Each stage runs under a named scope (stage.encode, stage.gossip,
+        stage.apply; the dither draw under stage.dither inside the encode),
+        the names dist/trainer.py's step uses: XLA keeps it in every op's
+        op_name metadata, so a profiler trace puts each device op down to
+        its stage."""
         gb = self._blockify_g(g)
 
         def comm(_):
-            payload, decode, bits, ctx = self.encode_stage(s, gb, key, hy)
-            q, wq = self.mix_payload(payload, decode, k=s.k)
-            new, comp_err = self.apply_stage(s, gb, q, wq, hy, ctx)
-            if self._hier:
-                new = self._intra_project(new)
+            with jax.named_scope("stage.encode"):
+                payload, decode, bits, ctx = self.encode_stage(s, gb, key,
+                                                               hy)
+            with jax.named_scope("stage.gossip"):
+                q, wq = self.mix_payload(payload, decode, k=s.k)
+            with jax.named_scope("stage.apply"):
+                new, comp_err = self.apply_stage(s, gb, q, wq, hy, ctx)
+                if self._hier:
+                    new = self._intra_project(new)
             return new, comp_err, bits
 
         tau = self.comm_interval
@@ -666,7 +680,8 @@ class FlatEngineBase:
             return comm(None)
 
         def local(_):
-            new, _ = self.local_stage(s, gb, hy)
+            with jax.named_scope("stage.apply"):
+                new, _ = self.local_stage(s, gb, hy)
             zero = jnp.zeros((), jnp.float32)
             return new, zero, zero
 
@@ -691,13 +706,16 @@ class FlatEngineBase:
         gb = self._blockify_g(g)
 
         def comm(_):
-            payload, decode, bits, ctx = self.encode_stage(state, gb, key,
-                                                           hy)
-            q, wq, fs = self.mix_payload_faulted(payload, decode, state.k,
-                                                 fstate)
-            new, comp_err = self.apply_stage(state, gb, q, wq, hy, ctx)
-            if self._hier:
-                new = self._intra_project(new)
+            with jax.named_scope("stage.encode"):
+                payload, decode, bits, ctx = self.encode_stage(state, gb,
+                                                               key, hy)
+            with jax.named_scope("stage.gossip"):
+                q, wq, fs = self.mix_payload_faulted(payload, decode,
+                                                     state.k, fstate)
+            with jax.named_scope("stage.apply"):
+                new, comp_err = self.apply_stage(state, gb, q, wq, hy, ctx)
+                if self._hier:
+                    new = self._intra_project(new)
             return new, fs, comp_err, bits
 
         tau = self.comm_interval
@@ -705,7 +723,8 @@ class FlatEngineBase:
             return comm(None)
 
         def local(_):
-            new, _ = self.local_stage(state, gb, hy)
+            with jax.named_scope("stage.apply"):
+                new, _ = self.local_stage(state, gb, hy)
             zero = jnp.zeros((), jnp.float32)
             return new, fstate, zero, zero
 

@@ -7,9 +7,9 @@ Each builder returns
 
     (fn, sds, shardings, cfg)
 
-where `sds` are ShapeDtypeStructs for lowering without allocation (the
-dry-run path) and `shardings` the matching NamedSharding pytrees — the
-contract launch/dryrun.py and the dist tests consume.
+where `sds` are ShapeDtypeStructs for lowering without allocation and
+`shardings` the matching NamedSharding pytrees — the contract the dist
+tests consume.
 """
 from __future__ import annotations
 

@@ -668,12 +668,20 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
                     out_specs=spec)(payloads, masks, step)
 
     # -- the step -----------------------------------------------------------
+    # Each stage of the step runs under a named scope (stage.grad,
+    # stage.encode, stage.gossip, stage.apply — the flat engines use the
+    # same names): XLA keeps it as a segment of every op's op_name
+    # metadata, so a profiler trace can put each device op down to its
+    # stage.  Metadata only: the compiled program is the same without it.
     def step(state: TrainState, batch: Dict[str, jnp.ndarray], key):
-        g = jax.vmap(agent_grad)(state.params, batch)
-        g = tree_map(lambda l: l.astype(jnp.float32), g)
-        direction, opt_state = dc.optimizer.update(g, state.opt, state.params)
-        gnorm = jnp.sqrt(sum(jnp.sum(l.astype(jnp.float32) ** 2)
-                             for l in jax.tree_util.tree_leaves(direction)))
+        with jax.named_scope("stage.grad"):
+            g = jax.vmap(agent_grad)(state.params, batch)
+            g = tree_map(lambda l: l.astype(jnp.float32), g)
+            direction, opt_state = dc.optimizer.update(g, state.opt,
+                                                       state.params)
+            gnorm = jnp.sqrt(sum(jnp.sum(l.astype(jnp.float32) ** 2)
+                                 for l in jax.tree_util.tree_leaves(
+                                     direction)))
         metrics = {"grad_norm": gnorm}
 
         if eng is None:                  # centralized allreduce reference
@@ -698,14 +706,15 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
         keys = jax.random.split(key, max(len(leaves_x), 1))
 
         states, gbs, d_leafs = [], [], []
-        for i, (lx, lg) in enumerate(zip(leaves_x, leaves_g)):
-            xb, d_leaf = _leaf_blocks(lx, dc.block)
-            gb, _ = _leaf_blocks(lg, dc.block)
-            fields = {f: _leaf_blocks(leaves_algo[f][i], dc.block)[0]
-                      for f in leaves_algo}
-            states.append(eng.state_cls(x=xb, k=state.step, **fields))
-            gbs.append(gb)
-            d_leafs.append(d_leaf)
+        with jax.named_scope("stage.encode"):
+            for i, (lx, lg) in enumerate(zip(leaves_x, leaves_g)):
+                xb, d_leaf = _leaf_blocks(lx, dc.block)
+                gb, _ = _leaf_blocks(lg, dc.block)
+                fields = {f: _leaf_blocks(leaves_algo[f][i], dc.block)[0]
+                          for f in leaves_algo}
+                states.append(eng.state_cls(x=xb, k=state.step, **fields))
+                gbs.append(gb)
+                d_leafs.append(d_leaf)
 
         def _unblock(new_states):
             new_x = [_leaf_unblocks(ns.x, lx)
@@ -725,98 +734,107 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
             # and gossip_payloads exchanges every flat entry unchanged.
             # bits_total sums over (leaf x wire): both buffers really
             # cross the wire each exchange.
-            n_wires = eng.n_wires
-            msgs, ctxs, wire_keys, wire_dims = [], [], [], []
-            for kk, s_leaf, gb, d_leaf in zip(keys, states, gbs, d_leafs):
-                msg, ctx = eng.message(s_leaf, gb, hy)
-                wires = msg if n_wires > 1 else (msg,)
-                assert len(wires) == n_wires, (eng.wire_fields, len(wires))
-                msgs.extend(wires)
-                wire_keys.extend([kk] if n_wires == 1 else
-                                 [jax.random.fold_in(kk, j)
-                                  for j in range(n_wires)])
-                wire_dims.extend([d_leaf] * n_wires)
-                ctxs.append(ctx)
-            if hier:
-                # exact block mean BEFORE encode: each node quantizes one
-                # shared message (per-lane dither — see gossip_payloads)
-                msgs = pmean_intra(msgs)
-            payloads = []
-            bits_total = jnp.zeros((), jnp.float32)
-            for kk, msg, d_leaf in zip(wire_keys, msgs, wire_dims):
-                if comp is not None:
-                    payload, bits = comp.encode_blocks(
-                        kk, msg, d_leaf, interpret=dc.interpret)
+            with jax.named_scope("stage.encode"):
+                n_wires = eng.n_wires
+                msgs, ctxs, wire_keys, wire_dims = [], [], [], []
+                for kk, s_leaf, gb, d_leaf in zip(keys, states, gbs,
+                                                  d_leafs):
+                    msg, ctx = eng.message(s_leaf, gb, hy)
+                    wires = msg if n_wires > 1 else (msg,)
+                    assert len(wires) == n_wires, (eng.wire_fields,
+                                                   len(wires))
+                    msgs.extend(wires)
+                    wire_keys.extend([kk] if n_wires == 1 else
+                                     [jax.random.fold_in(kk, j)
+                                      for j in range(n_wires)])
+                    wire_dims.extend([d_leaf] * n_wires)
+                    ctxs.append(ctx)
+                if hier:
+                    # exact block mean BEFORE encode: each node quantizes
+                    # one shared message (per-lane dither — see
+                    # gossip_payloads)
+                    msgs = pmean_intra(msgs)
+                payloads = []
+                bits_total = jnp.zeros((), jnp.float32)
+                for kk, msg, d_leaf in zip(wire_keys, msgs, wire_dims):
+                    if comp is not None:
+                        payload, bits = comp.encode_blocks(
+                            kk, msg, d_leaf, interpret=dc.interpret)
+                    else:
+                        payload = {"values": msg}
+                        bits = jnp.asarray(d_leaf * 32, jnp.float32)
+                    payloads.append(payload)
+                    bits_total = bits_total + bits
+
+            with jax.named_scope("stage.gossip"):
+                masks = None
+                dropped = jnp.zeros((), jnp.float32)
+                if fm is not None:
+                    # (R_max, A) survival masks for the LIVE round graph
+                    # only: select the step's receive sources first (step %
+                    # P), then realize the counter-hash link_ok over them —
+                    # same realization the simulator uses (keyed on
+                    # state.step — replayable across restarts and
+                    # checkpoints), but the hash and reduction work never
+                    # touches the P-1 graphs that are not exchanged this
+                    # step.  Padded rows (src -1) are masked by `present`, so
+                    # dropped_links counts real edges of round step % P
+                    # alone; on interval runs the whole block sits inside the
+                    # comm branch, so skipped steps realize (and report) no
+                    # faults at all.
+                    src_sel = (jnp.asarray(src_stack[0]) if P_bank == 1
+                               else jnp.take(jnp.asarray(src_stack),
+                                             state.step % P_bank, axis=0))
+                    present = src_sel >= 0
+                    masks = fm.link_ok(state.step, src_sel,
+                                       jnp.arange(A)) & present
+                    dropped = jnp.sum(present & ~masks).astype(jnp.float32)
+                q_wqs = gossip_payloads(
+                    payloads, masks, step=state.step if P_bank > 1 else None)
+                if n_wires > 1:
+                    # regroup the flat (leaf x wire) results back to one
+                    # (q-tuple, wq-tuple) pair per leaf — the shape
+                    # apply_stage expects from a multi-wire engine
+                    q_wqs = [(tuple(q for q, _ in q_wqs[i:i + n_wires]),
+                              tuple(wq for _, wq in q_wqs[i:i + n_wires]))
+                             for i in range(0, len(q_wqs), n_wires)]
+
+            with jax.named_scope("stage.apply"):
+                def apply_leaves(sts, gs, qws, cs, h):
+                    return [eng.apply_stage(s_leaf, gb, q, wq, h, ctx)[0]
+                            for s_leaf, gb, (q, wq), ctx
+                            in zip(sts, gs, qws, cs)]
+
+                if is_bank:
+                    # a bank's apply stage re-mixes H with the step's
+                    # graph, so it needs the whole agent axis
+                    new_states = apply_leaves(states, gbs, q_wqs, ctxs, hy)
                 else:
-                    payload = {"values": msg}
-                    bits = jnp.asarray(d_leaf * 32, jnp.float32)
-                payloads.append(payload)
-                bits_total = bits_total + bits
-
-            masks = None
-            dropped = jnp.zeros((), jnp.float32)
-            if fm is not None:
-                # (R_max, A) survival masks for the LIVE round graph only:
-                # select the step's receive sources first (step % P), then
-                # realize the counter-hash link_ok over them — same
-                # realization the simulator uses (keyed on state.step —
-                # replayable across restarts and checkpoints), but the hash
-                # and reduction work never touches the P-1 graphs that are
-                # not exchanged this step.  Padded rows (src -1) are masked
-                # by `present`, so dropped_links counts real edges of round
-                # step % P alone; on interval runs the whole block sits
-                # inside the comm branch, so skipped steps realize (and
-                # report) no faults at all.
-                src_sel = (jnp.asarray(src_stack[0]) if P_bank == 1
-                           else jnp.take(jnp.asarray(src_stack),
-                                         state.step % P_bank, axis=0))
-                present = src_sel >= 0
-                masks = fm.link_ok(state.step, src_sel,
-                                   jnp.arange(A)) & present
-                dropped = jnp.sum(present & ~masks).astype(jnp.float32)
-            q_wqs = gossip_payloads(payloads, masks,
-                                    step=state.step if P_bank > 1 else None)
-            if n_wires > 1:
-                # regroup the flat (leaf x wire) results back to one
-                # (q-tuple, wq-tuple) pair per leaf — the shape apply_stage
-                # expects from a multi-wire engine
-                q_wqs = [(tuple(q for q, _ in q_wqs[i:i + n_wires]),
-                          tuple(wq for _, wq in q_wqs[i:i + n_wires]))
-                         for i in range(0, len(q_wqs), n_wires)]
-
-            def apply_leaves(sts, gs, qws, cs, h):
-                return [eng.apply_stage(s_leaf, gb, q, wq, h, ctx)[0]
-                        for s_leaf, gb, (q, wq), ctx in zip(sts, gs, qws, cs)]
-
-            if is_bank:
-                # a bank's apply stage re-mixes H with the step's graph, so
-                # it needs the whole agent axis
-                new_states = apply_leaves(states, gbs, q_wqs, ctxs, hy)
-            else:
-                # every static-graph apply stage is per-agent algebra: run
-                # it on each device's own agents, where a Pallas kernel
-                # (which XLA cannot partition) sees a local buffer
-                args = (states, gbs, q_wqs, ctxs, hy)
-                specs = tree_map(lambda l: spec if jnp.ndim(l) else P(),
-                                 args)
-                new_states = smap(apply_leaves, in_specs=specs,
-                                  out_specs=specs[0])(*args)
-            new_x, new_algo = _unblock(new_states)
-            if hier:
-                # project the FULL state back to block-constant — each node
-                # is one logical agent (P W = W P keeps LEAD's hw = W h
-                # invariant) — and count leader-lane bits only: the s lanes
-                # of a node carry one logical payload each round
-                new_x = pmean_intra(new_x)
-                new_algo = {f: pmean_intra(ls)
-                            for f, ls in new_algo.items()}
-                bits_total = bits_total / node_size
+                    # every static-graph apply stage is per-agent algebra:
+                    # run it on each device's own agents, where a Pallas
+                    # kernel (which XLA cannot partition) sees a local buffer
+                    args = (states, gbs, q_wqs, ctxs, hy)
+                    specs = tree_map(lambda l: spec if jnp.ndim(l) else P(),
+                                     args)
+                    new_states = smap(apply_leaves, in_specs=specs,
+                                      out_specs=specs[0])(*args)
+                new_x, new_algo = _unblock(new_states)
+                if hier:
+                    # project the FULL state back to block-constant — each
+                    # node is one logical agent (P W = W P keeps LEAD's hw =
+                    # W h invariant) — and count leader-lane bits only: the s
+                    # lanes of a node carry one logical payload each round
+                    new_x = pmean_intra(new_x)
+                    new_algo = {f: pmean_intra(ls)
+                                for f, ls in new_algo.items()}
+                    bits_total = bits_total / node_size
             return new_x, new_algo, bits_total, dropped
 
         def local(_):
-            new_states = [eng.local_stage(s_leaf, gb, hy)[0]
-                          for s_leaf, gb in zip(states, gbs)]
-            new_x, new_algo = _unblock(new_states)
+            with jax.named_scope("stage.apply"):
+                new_states = [eng.local_stage(s_leaf, gb, hy)[0]
+                              for s_leaf, gb in zip(states, gbs)]
+                new_x, new_algo = _unblock(new_states)
             zero = jnp.zeros((), jnp.float32)
             return new_x, new_algo, zero, zero
 

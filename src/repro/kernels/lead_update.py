@@ -73,6 +73,7 @@ def lead_update(x, g, d, h, hw, qh, wqh, eta, gamma, alpha, *,
         out_specs=[tile] * 4,
         out_shape=[out_sds] * 4,
         interpret=(backend == "interpret"),
+        name="lead_update",
     )(scal(eta), scal(gamma), scal(alpha), x, g, d, h, hw, qh, wqh)
 
 
@@ -116,4 +117,5 @@ def lead_diff_encode(x, g, d, h, u, eta, *, bits: int = 2,
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=(backend == "interpret"),
+        name="lead_diff_encode",
     )(jnp.asarray(eta, jnp.float32).reshape(1, 1), x, g, d, h, u)

@@ -94,6 +94,7 @@ def encode(x: jnp.ndarray, u: jnp.ndarray, *, bits: int = 2,
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=(backend == "interpret"),
+        name="quantize_encode",
     )(x, u)
 
 
@@ -117,4 +118,5 @@ def decode(code: jnp.ndarray, scale: jnp.ndarray, *, bits: int = 2,
         out_specs=pl.BlockSpec((tile_b, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=(backend == "interpret"),
+        name="quantize_decode",
     )(code, scale)

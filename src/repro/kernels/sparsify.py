@@ -56,6 +56,7 @@ def randk_encode(x: jnp.ndarray, u: jnp.ndarray, *, ratio: float,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=(backend == "interpret"),
+        name="randk_encode",
     )(x, u)
 
 
@@ -81,4 +82,5 @@ def mask_apply(x: jnp.ndarray, mask: jnp.ndarray, *,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=(backend == "interpret"),
+        name="mask_apply",
     )(x, mask.astype(jnp.float32))

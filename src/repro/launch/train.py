@@ -184,26 +184,35 @@ def loop(run: Run, before_step: Optional[Callable] = None,
     ``before_step(i, state, batch, key) -> state`` may replace the state a
     step starts from; ``after_step(i, state, batch, metrics)`` sees what the
     step returned.  The step donates its input state, so a hook keeps what
-    it needs of a state before the step that consumes it."""
+    it needs of a state before the step that consumes it.
+
+    Each step is a profiler step span ``train`` (step_num i) and its batch
+    fetch a span ``train.get_batch``: under ``jax.profiler.trace`` they lie
+    on the device ops' clock, so an idle gap on the chip can be put down to
+    the host phase that caused it.  Without a profiler they cost nothing."""
     args = run.args
     state, start = run.state, run.start
     with jax.set_mesh(run.mesh):
         t0 = time.time()
         for i in range(start, start + args.steps):
-            batch = run.get_batch(i)
-            key_i = jax.random.fold_in(run.key, i)
-            if before_step is not None:
-                state = before_step(i, state, batch, key_i)
-            state, metrics = run.step_fn(state, batch, key_i)
-            if after_step is not None:
-                after_step(i, state, batch, metrics)
-            if (i + 1) % args.log_every == 0 or i == start:
-                losses = run.loss_fn(state.params, batch)
-                print(f"step {i+1:5d} | loss {float(jnp.mean(losses)):.4f} | "
-                      f"grad_norm {float(metrics['grad_norm']):.3f} | "
-                      f"{(time.time()-t0)/(i-start+1):.2f}s/step", flush=True)
-            if args.ckpt_dir and (i + 1) % 100 == 0:
-                ckpt.save(args.ckpt_dir, i + 1, jax.device_get(state))
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                with jax.profiler.TraceAnnotation("train.get_batch"):
+                    batch = run.get_batch(i)
+                key_i = jax.random.fold_in(run.key, i)
+                if before_step is not None:
+                    state = before_step(i, state, batch, key_i)
+                state, metrics = run.step_fn(state, batch, key_i)
+                if after_step is not None:
+                    after_step(i, state, batch, metrics)
+                if (i + 1) % args.log_every == 0 or i == start:
+                    losses = run.loss_fn(state.params, batch)
+                    print(f"step {i+1:5d} | "
+                          f"loss {float(jnp.mean(losses)):.4f} | "
+                          f"grad_norm {float(metrics['grad_norm']):.3f} | "
+                          f"{(time.time()-t0)/(i-start+1):.2f}s/step",
+                          flush=True)
+                if args.ckpt_dir and (i + 1) % 100 == 0:
+                    ckpt.save(args.ckpt_dir, i + 1, jax.device_get(state))
         if args.ckpt_dir:
             ckpt.save(args.ckpt_dir, start + args.steps, jax.device_get(state))
     run.state = state

@@ -1,6 +1,6 @@
 # NOTE: do NOT set --xla_force_host_platform_device_count here.  Smoke tests
-# and benches must see the real single device; only launch/dryrun.py (and the
-# subprocess-based distributed tests) force placeholder devices.
+# and benches must see the real single device; only the subprocess-based
+# distributed tests force placeholder devices.
 import os
 import time
 
