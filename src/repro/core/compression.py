@@ -26,16 +26,29 @@ Every operator implements the `Compressor` protocol:
 plus the *flat-layout wire path* used by the flat LEAD engine
 (core/engine.py) and the distributed trainer (dist/trainer.py), operating on
 the kernels' blocked ``(n_agents, nb, block)`` f32 buffers (zero-padded past
-the logical per-agent dimension ``dim``):
+the logical per-agent dimension ``dim``).  An operator whose blocks are
+contiguous lane segments says so with ``row_segments = True`` (QuantizePNorm,
+Identity) and also takes ``(n_agents, rows, k * block)`` buffers, rows *
+k * block == dim, block j of a row being its lane segment j: the trainer's
+tiled view of a model leaf (kernels/__init__.py).  Its scales are then
+``(n_agents, rows, k)``.
 
-    encode_blocks(key, buf, dim) -> (payload, bits)
+    encode_blocks(key, buf, dim, interpret=None) -> (payload, bits)
         payload: dict of arrays with leading agent axis n — exactly what
         crosses agents in encoded gossip (the trainer's per-round ppermute
         exchange; EncodedNeighborGossip models it on the flat agent axis);
         nothing outside the payload may travel.
         bits: scalar f32, bits per agent actually on the wire THIS step,
         computed from the payload (for RandK this is data-dependent).
-    decode_blocks(payload) -> (n, nb, block) f32 decoded estimate.
+    decode_blocks(payload, interpret=None) -> the buffer's shape, f32
+        decoded estimate.
+
+A ``row_segments`` operator also has ``encode_agents(keys, buf, dim,
+interpret=None)``: encode_blocks with each agent's own key given, ``keys``
+being the (n,) keys encode_blocks splits from its one key.  Each agent's
+payload then depends on its own key and buffer alone, so the trainer runs
+it inside its shard_map on each device's own agents (XLA cannot partition
+the quantize kernel over the agent axis).
 
 The shared-randomness contract: encode_blocks splits `key` into one key per
 agent exactly like simulator.vmap_compress does, so flat-engine trajectories
@@ -84,8 +97,12 @@ def _unblock(blocks: jnp.ndarray, n: int, shape):
     return jnp.reshape(jnp.ravel(blocks)[:n], shape)
 
 
+def _is_inf(p) -> bool:
+    return p == jnp.inf or p == math.inf or p == "inf"
+
+
 def _pnorm(x: jnp.ndarray, p, axis=-1, keepdims=True):
-    if p == jnp.inf or p == math.inf or p == "inf":
+    if _is_inf(p):
         return jnp.max(jnp.abs(x), axis=axis, keepdims=keepdims)
     return jnp.sum(jnp.abs(x) ** p, axis=axis, keepdims=keepdims) ** (1.0 / p)
 
@@ -169,32 +186,70 @@ class QuantizePNorm:
         return n_elements * (self.bits + 1) + nb * 32  # +1: sign bit
 
     # -- flat-layout wire path (engine / dist trainer) ----------------------
+    # encode_blocks / decode_blocks also take (n, rows, k * block) buffers,
+    # block j of a row being its lane segment j (the trainer's tiled leaf
+    # view, dist/trainer.py)
+    row_segments = True
+
     def encode_blocks(self, key, buf: jnp.ndarray, dim: int,
                       interpret: Optional[bool] = None):
-        """buf: (n, nb, block) f32, zero-padded past dim.  Per-agent dither is
-        drawn exactly as the tree path does (split key, uniform over the
-        logical (ceil(dim/block), block) block matrix), so the payload matches
-        vmap_compress + encode draw for draw.  (The p=inf engine hot path uses
-        the fused lead_diff_encode kernel instead; this generic path serves
-        p != inf and the dist trainer, where XLA fuses it.)"""
-        del interpret                    # pure-XLA path; kept for protocol
-        n, nb, block = buf.shape
-        assert block == self.block, (block, self.block)
+        """buf: (n, nb, block) f32, zero-padded past dim, or (n, rows, k *
+        block) with rows * k * block == dim.  Per-agent dither is drawn
+        exactly as the tree path does (split key, uniform over the logical
+        (ceil(dim/block), block) block matrix; a (rows, k * block) draw is
+        the same numbers, as the threefry counter is the row-major index),
+        so the payload matches vmap_compress + encode draw for draw.  (The
+        p=inf engine hot path uses the fused lead_diff_encode kernel
+        instead; this generic path serves p != inf and the dist trainer,
+        where XLA fuses it, and rows of k blocks take the quantize kernel,
+        which reduces each lane segment in place.)"""
+        return self.encode_agents(jax.random.split(key, buf.shape[0]), buf,
+                                  dim, interpret=interpret)
+
+    def encode_agents(self, keys, buf: jnp.ndarray, dim: int,
+                      interpret: Optional[bool] = None):
+        """encode_blocks with agent i's dither drawn from ``keys[i]``."""
+        n, rows, width = buf.shape
+        block = self.block
+        assert width % block == 0, (width, block)
         nbl = _nb_logical(dim, block)
-        keys = jax.random.split(key, n)
-        u = jax.vmap(lambda kk: jax.random.uniform(
-            kk, (nbl, block), jnp.float32))(keys)
-        u = jnp.pad(u, ((0, 0), (0, nb - nbl), (0, 0)))
-        code, scale = _stochastic_quantize(buf, u, self.bits, self.p)
+        if width == block:
+            u = jax.vmap(lambda kk: jax.random.uniform(
+                kk, (nbl, block), jnp.float32))(keys)
+            u = jnp.pad(u, ((0, 0), (0, rows - nbl), (0, 0)))
+            code, scale = _stochastic_quantize(buf, u, self.bits, self.p)
+        else:
+            assert rows * width == dim, (buf.shape, dim)
+            k = width // block
+            u = jax.vmap(lambda kk: jax.random.uniform(
+                kk, (rows, width), jnp.float32))(keys)
+            if _is_inf(self.p):
+                from repro.kernels import quantize as _q
+                code, scale = _q.encode(
+                    buf.reshape(n * rows, width), u.reshape(n * rows, width),
+                    bits=self.bits, block=block, interpret=interpret)
+            else:
+                segs = lambda a: a.reshape(n, rows, k, block)
+                code, scale = _stochastic_quantize(segs(buf), segs(u),
+                                                   self.bits, self.p)
+            code, scale = code.reshape(buf.shape), scale.reshape(n, rows, k)
         payload = {"code": code, "scale": scale}
         # actual payload: (b+1)-bit codes for the dim logical elements + one
         # f32 scale per logical block (the padded tail rows never travel).
         bits = jnp.asarray(dim * (self.bits + 1) + nbl * 32, jnp.float32)
         return payload, bits
 
-    def decode_blocks(self, payload: dict) -> jnp.ndarray:
-        return (payload["scale"] * (2.0 ** (1 - self.bits))
-                * payload["code"].astype(jnp.float32))
+    def decode_blocks(self, payload: dict,
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
+        code, scale = payload["code"], payload["scale"]
+        if scale.shape[-1] == 1:
+            return scale * (2.0 ** (1 - self.bits)) * code.astype(jnp.float32)
+        from repro.kernels import quantize as _q
+        width = code.shape[-1]
+        rows = _q.decode(code.reshape(-1, width),
+                         scale.reshape(-1, scale.shape[-1]), bits=self.bits,
+                         interpret=interpret)
+        return rows.reshape(code.shape)
 
     def variance_constant(self, d_block: Optional[int] = None) -> float:
         """Upper bound on C in  E||x - Q(x)||^2 <= C ||x||^2  (Remark 7).
@@ -303,7 +358,9 @@ class TopK:
         payload = {"values": vals.reshape(n, nb, block)}
         return payload, bits
 
-    def decode_blocks(self, payload: dict) -> jnp.ndarray:
+    def decode_blocks(self, payload: dict,
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
+        del interpret
         return payload["values"]
 
     def variance_constant(self, d_block=None):
@@ -362,7 +419,9 @@ class RandK:
                                 axis=1)) * 32.0
         return payload, bits
 
-    def decode_blocks(self, payload: dict) -> jnp.ndarray:
+    def decode_blocks(self, payload: dict,
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
+        del interpret
         return payload["values"]
 
     def variance_constant(self, d_block=None):
@@ -388,12 +447,18 @@ class Identity:
         return n_elements * 32
 
     # -- flat-layout wire path ----------------------------------------------
+    row_segments = True                  # any buffer shape: the raw values
+
     def encode_blocks(self, key, buf: jnp.ndarray, dim: int,
                       interpret: Optional[bool] = None):
         del key, interpret
         return {"values": buf}, jnp.asarray(dim * 32, jnp.float32)
 
-    def decode_blocks(self, payload: dict) -> jnp.ndarray:
+    encode_agents = encode_blocks        # no key is drawn from
+
+    def decode_blocks(self, payload: dict,
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
+        del interpret
         return payload["values"]
 
     def variance_constant(self, d_block=None):

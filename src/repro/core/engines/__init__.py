@@ -121,7 +121,8 @@ _TREE_TWINS = {
 def engine_for(topology, compressor, dim: int,
                interpret: Optional[bool] = None,
                dither: str = "match", gossip: str = "dense",
-               algorithm: str = "lead", faults=None, **hyper) -> FlatEngineBase:
+               algorithm: str = "lead", faults=None, in_place: bool = False,
+               **hyper) -> FlatEngineBase:
     """Registry dispatch: (algorithm, compressor, topology) -> flat engine.
 
     `topology` is a core/topology.Topology — built by topology.ring(n),
@@ -152,7 +153,9 @@ def engine_for(topology, compressor, dim: int,
     communication stage through the engine's masked-mixing path
     (step_with_wire_faulted) with deterministic, replayable link drops,
     agent dropout, stragglers, and payload corruption.  None (the default)
-    leaves the clean path untouched.
+    leaves the clean path untouched.  `in_place` lets the apply stage's
+    kernel write the new state over the old one's buffers (for callers that
+    donate the state; see FlatEngineBase).
     """
     from repro.core.compression import Identity
 
@@ -175,7 +178,7 @@ def engine_for(topology, compressor, dim: int,
     block = getattr(compressor, "block", DEFAULT_BLOCK)
     return cls(topology=topology, dim=dim, compressor=compressor, block=block,
                interpret=interpret, gossip=gossip, dither=dither,
-               faults=faults, **hyper)
+               faults=faults, in_place=in_place, **hyper)
 
 
 def flat_twin(algo, dim: int, *, gossip: str = "dense",

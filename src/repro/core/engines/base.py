@@ -81,7 +81,7 @@ derives ``step`` / ``step_with_metrics`` / ``x_of`` from that one method.
 from __future__ import annotations
 
 import dataclasses
-import math
+import functools
 from typing import Any, ClassVar, Dict, Optional
 
 import jax
@@ -103,9 +103,8 @@ from repro.kernels.ops import DEFAULT_BLOCK, _pick_tile
 def _is_fused_quantizer(comp) -> bool:
     """True when the compressor is exactly what the fused Pallas kernels
     implement: the blockwise p=inf b-bit quantizer."""
-    from repro.core.compression import QuantizePNorm
-    return (isinstance(comp, QuantizePNorm)
-            and comp.p in (jnp.inf, math.inf, "inf"))
+    from repro.core.compression import QuantizePNorm, _is_inf
+    return isinstance(comp, QuantizePNorm) and _is_inf(comp.p)
 
 
 def fast_uniform(shape, seed: jnp.ndarray) -> jnp.ndarray:
@@ -143,7 +142,10 @@ class FlatEngineBase:
     the alias that additionally asserts the topology is the uniform ring.
     dither selects the quantizer dither stream (see module docstring);
     "match" keeps trajectories aligned with the tree references, "fast" is
-    the cheaper production stream.
+    the cheaper production stream.  in_place lets the apply stage's kernel
+    write the new state over the old state's buffers (kernels/lead_update.py
+    ``in_place``): for a caller that donates the state, as dist/trainer.py's
+    step does.
 
     Subclasses add their hyper-parameter fields (eta/gamma/...), each a
     ``Schedule``: a float or a callable of the iteration counter k
@@ -162,6 +164,7 @@ class FlatEngineBase:
     gossip: str = "dense"              # "dense" | "neighbor" | "ring" alias
     dither: str = "match"              # "match" | "fast"
     faults: Optional[Any] = None       # core/faults.FaultModel (None = clean)
+    in_place: bool = False             # apply kernels write over the old state
 
     # subclass metadata: the state NamedTuple and its consensus start
     # (field -> "copy" of x0 | "zeros"); x and k are implicit
@@ -394,7 +397,8 @@ class FlatEngineBase:
             return self.quant_payload(code, scale, comp.bits)
         payload, bits = comp.encode_blocks(key, buf, self.dim,
                                            interpret=self.interpret)
-        return payload, comp.decode_blocks, bits
+        return (payload, functools.partial(comp.decode_blocks,
+                                           interpret=self.interpret), bits)
 
     def quant_payload(self, code: jnp.ndarray, scale: jnp.ndarray,
                       bits: int):

@@ -183,7 +183,7 @@ class FlatLEADEngine(FlatEngineBase):
             self._rows(s.x), self._rows(gb), self._rows(s.d),
             self._rows(s.h), self._rows(s.hw), self._rows(qh),
             self._rows(wqh), hy["eta"], hy["gamma"], hy["alpha"],
-            interpret=self.interpret)
+            in_place=self.in_place, interpret=self.interpret)
         shape3 = s.x.shape
         new = FlatLEADState(x=xo.reshape(shape3), d=do.reshape(shape3),
                             h=ho.reshape(shape3), hw=hwo.reshape(shape3),

@@ -6,8 +6,10 @@ as the single-device simulator (core/engines): LEAD and every paper
 baseline — CHOCO-SGD, DeepSqueeze, QDGD, DCD-SGD compressed; DGD, NIDS,
 EXTRA, D2 exact — run multi-host from one implementation of their update
 math.  The trainer holds NO per-algorithm algebra of its own: each step it
-blockifies every stacked train-state leaf into the kernels' ``(A, nb,
-block)`` layout, calls the engine's ``message`` stage, ships the encoded
+hands every stacked train-state leaf to the kernels as ``(A, rows, C)``
+rows of its own last dim C (a bitcast of the leaf; see _leaf_view) or,
+where C is not a multiple of the block, as the flattened leaf padded to
+``(A, nb, block)``, calls the engine's ``message`` stage, ships the encoded
 payload through the ring, and calls the engine's ``apply_stage``
 (engines/base.py documents the stage protocol).  ``allreduce`` is the one
 special case — it is not a decentralized algorithm but the centralized
@@ -51,15 +53,17 @@ realize link drops only on the rounds that actually fire.
 
 Codes on the wire: compressed algorithms encode each leaf's message with
 the Compressor flat protocol (``encode_blocks`` / ``decode_blocks``,
-core/compression.py) *before* the shard_map; inside it only the payload
-(int8 code planes + per-block f32 scales for the quantizer; kept values for
-RandK/TopK) crosses agents — each gossip round's ppermute output is
-decoded at the receiver.  Exact algorithms ship the raw f32 leaf (d * 32
-bits).  With
-``wire_pack=True`` quantizer codes additionally travel as dense uint32
-words (kernels.ops.pack_codes) — the byte-accurate ICI payload.  Each
-step's metrics include ``bits_per_agent``, the actual payload bits summed
-over leaves — the same accounting as Trace.bits_per_agent in the simulator.
+core/compression.py) *before* the gossip shard_map — the quantizer in a
+shard_map of its own, on each device's own agents (``encode_agents``), as
+XLA cannot partition its Pallas kernel; inside the gossip shard_map only
+the payload (int8 code planes + per-block f32 scales for the quantizer;
+kept values for RandK/TopK) crosses agents — each gossip round's ppermute
+output is decoded at the receiver.  Exact algorithms ship the raw f32 leaf
+(d * 32 bits).  With ``wire_pack=True`` quantizer codes additionally travel
+as dense uint32 words (kernels.ops.pack_codes) — the byte-accurate ICI
+payload.  Each step's metrics include ``bits_per_agent``, the actual
+payload bits summed over leaves — the same accounting as
+Trace.bits_per_agent in the simulator.
 
 Hyper-parameters (``DistConfig.hyper``) are Schedule values — floats or
 callables of the step counter (Theorem 2 diminishing stepsizes) — resolved
@@ -96,6 +100,7 @@ from repro.core.engines.base import _LAYOUT_FIELDS
 from repro.core.lead import LEADHyper, _at
 from repro.dist import sharding as shr
 from repro.kernels.ops import pack_codes, unpack_codes
+from repro.kernels.quantize import MAX_WIDTH
 from repro.models import transformer as tfm
 from repro.optim.optimizers import SGD
 from repro.utils.finite import assert_finite_tree, finite_checks_enabled
@@ -271,9 +276,11 @@ def engine_of(dc: DistConfig, n_agents: int):
     # host-numpy Topology: engine_of may run inside a jitted init trace,
     # where a jnp constant would become a tracer and break validation
     topo = topology_of(dc, n_agents)
+    # in_place: the step donates its state, so the apply kernel writes the
+    # new state over the old leaves' buffers
     return engine_for(topo, comp, dim=dc.block, interpret=dc.interpret,
                       gossip="neighbor", algorithm=dc.algorithm,
-                      faults=dc.faults, **hyp)
+                      faults=dc.faults, in_place=True, **hyp)
 
 
 def _hyper_fields_of(algorithm: str) -> set:
@@ -333,12 +340,41 @@ def init_train_state(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig,
 
 
 # ---------------------------------------------------------------------------
-# leaf layout (the kernels' block layout, per stacked leaf)
+# leaf layout (the kernels' buffers, per stacked leaf)
 # ---------------------------------------------------------------------------
 
-def _leaf_blocks(l: jnp.ndarray, block: int):
-    """Stacked leaf (A, ...) -> ((A, nb, block) f32, d_leaf)."""
+def _leaf_view(shape, block: int, comp) -> Optional[tuple]:
+    """(rows, width) of a stacked leaf's tiled view, or None for the padded
+    path.
+
+    A leaf (A, ..., C) whose last dim C is a multiple of ``block`` (and at
+    most quantize.MAX_WIDTH) reaches the engine as (A, rows, C), rows the
+    product of the dims between the agent axis and the last (1 for a 1-D
+    leaf).  On the TPU an f32 leaf is stored in (8, 128) tiles of its last
+    two dims, so this view is a bitcast of it, where rows of ``block``
+    taken from its flattening are a copy each way.  (A leaf whose
+    second-to-last dim is no multiple of 8, granite's (49155, 2048)
+    embedding, is stored in (1, 128) tiles instead and crosses to (8, 128)
+    and back on either path.)  Flat block j of the
+    leaf is lane segment j % (C / block) of row j // (C / block): the
+    block, its scale and its dither are those of the padded path.  A
+    compressor whose blocks are not contiguous lane segments (no
+    ``row_segments``: RandK's shared-seed mask, TopK's indices) keeps the
+    padded path."""
+    if comp is not None and not getattr(comp, "row_segments", False):
+        return None
+    if len(shape) < 2 or shape[-1] % block or shape[-1] > MAX_WIDTH:
+        return None
+    return int(np.prod(shape[1:-1], dtype=np.int64)), int(shape[-1])
+
+
+def _leaf_blocks(l: jnp.ndarray, block: int, view: Optional[tuple] = None):
+    """Stacked leaf (A, ...) -> (buffer f32, d_leaf): the (A, rows, width)
+    view when given, else the flattened leaf zero-padded to (A, nb,
+    block)."""
     A = l.shape[0]
+    if view is not None:
+        return l.reshape((A,) + view).astype(jnp.float32), view[0] * view[1]
     flat = l.reshape(A, -1).astype(jnp.float32)
     d_leaf = flat.shape[1]
     nb = -(-d_leaf // block)
@@ -346,10 +382,25 @@ def _leaf_blocks(l: jnp.ndarray, block: int):
     return jnp.pad(flat, ((0, 0), (0, pad))).reshape(A, nb, block), d_leaf
 
 
-def _leaf_unblocks(buf: jnp.ndarray, like: jnp.ndarray) -> jnp.ndarray:
+def _leaf_unblocks(buf: jnp.ndarray, like: jnp.ndarray,
+                   view: Optional[tuple] = None) -> jnp.ndarray:
+    if view is not None:
+        return buf.reshape(like.shape).astype(like.dtype)
     A = like.shape[0]
     flat = buf.reshape(A, -1)[:, :like[0].size]
     return flat.reshape(like.shape).astype(like.dtype)
+
+
+def _layout_counts(leaves, views) -> Dict[str, int]:
+    """Leaves, and elements per agent, on the tiled view and on the padded
+    path."""
+    out = {"tiled_leaves": 0, "tiled_elements": 0, "padded_leaves": 0,
+           "padded_elements": 0}
+    for l, v in zip(leaves, views):
+        kind = "padded" if v is None else "tiled"
+        out[f"{kind}_leaves"] += 1
+        out[f"{kind}_elements"] += int(np.prod(l.shape[1:], dtype=np.int64))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +418,10 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
     step.  Hierarchical topologies report leader-lane bits (payload /
     node_size — intra-node traffic is free); interval topologies report
     0.0 bits and 0.0 dropped_links on skipped steps.
+
+    ``step.layout`` counts the model's leaves (and their elements per
+    agent) that reach the engine in their tiled view and those on the
+    padded path (see _leaf_view); None for allreduce.
     """
     cfg_fwd = cfg
     if dc.seq_parallel and prof.tp_axis and cfg.seq_shard_axis is None:
@@ -620,20 +675,22 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
             outs = []
             for pl in pls:
                 if dc.wire_pack and "code" in pl:
-                    code_shape = pl["code"].shape    # local (1, nb, block)
+                    code_shape = pl["code"].shape    # local (1, rows, width)
 
                     def dec(w, shape=code_shape):
                         code = unpack_codes(w["packed"], int(np.prod(shape)),
                                             comp.bits).reshape(shape)
                         return comp.decode_blocks(
-                            {"code": code, "scale": w["scale"]})
+                            {"code": code, "scale": w["scale"]},
+                            interpret=dc.interpret)
 
                     wire = {"packed": pack_codes(pl["code"], comp.bits),
                             "scale": pl["scale"]}
                 else:
                     wire = pl
-                    dec = (comp.decode_blocks if comp is not None
-                           else (lambda w: w["values"]))
+                    dec = (functools.partial(comp.decode_blocks,
+                                             interpret=dc.interpret)
+                           if comp is not None else (lambda w: w["values"]))
                 own = dec(wire)
                 if P_bank == 1:
                     wq = mix_one(schedules[0], own, wire, dec, msks)
@@ -666,6 +723,30 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
                         out_specs=spec)(payloads, masks)
         return smap(body, in_specs=(spec, P(), P()),
                     out_specs=spec)(payloads, masks, step)
+
+    def encode_payloads(keys, msgs, dims):
+        """Each wire's (payload, bits per agent), as lists.  A row-segment
+        operator (QuantizePNorm, Identity) encodes inside a shard_map, each
+        device its own agents with the keys encode_blocks would split for
+        them: its quantize kernel is a Pallas call, which XLA cannot
+        partition over the agent axis.  Other operators encode the whole
+        agent axis (encode_blocks); exact algorithms ship the raw buffer."""
+        if comp is None:
+            return ([{"values": m} for m in msgs],
+                    [jnp.asarray(d * 32, jnp.float32) for d in dims])
+        if not getattr(comp, "row_segments", False):
+            out = [comp.encode_blocks(kk, m, d, interpret=dc.interpret)
+                   for kk, m, d in zip(keys, msgs, dims)]
+            return [p for p, _ in out], [b for _, b in out]
+
+        def body(agent_keys, bufs):
+            out = [comp.encode_agents(ks, m, d, interpret=dc.interpret)
+                   for ks, m, d in zip(agent_keys, bufs, dims)]
+            return [p for p, _ in out], [b for _, b in out]
+
+        agent_keys = [jax.random.split(kk, A) for kk in keys]
+        return smap(body, in_specs=(spec, spec),
+                    out_specs=(spec, P()))(agent_keys, msgs)
 
     # -- the step -----------------------------------------------------------
     # Each stage of the step runs under a named scope (stage.grad,
@@ -705,22 +786,23 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
                        for f in eng.consensus_init}
         keys = jax.random.split(key, max(len(leaves_x), 1))
 
+        views = [_leaf_view(lx.shape, dc.block, comp) for lx in leaves_x]
         states, gbs, d_leafs = [], [], []
         with jax.named_scope("stage.encode"):
-            for i, (lx, lg) in enumerate(zip(leaves_x, leaves_g)):
-                xb, d_leaf = _leaf_blocks(lx, dc.block)
-                gb, _ = _leaf_blocks(lg, dc.block)
-                fields = {f: _leaf_blocks(leaves_algo[f][i], dc.block)[0]
+            for i, (lx, lg, v) in enumerate(zip(leaves_x, leaves_g, views)):
+                xb, d_leaf = _leaf_blocks(lx, dc.block, v)
+                gb, _ = _leaf_blocks(lg, dc.block, v)
+                fields = {f: _leaf_blocks(leaves_algo[f][i], dc.block, v)[0]
                           for f in leaves_algo}
                 states.append(eng.state_cls(x=xb, k=state.step, **fields))
                 gbs.append(gb)
                 d_leafs.append(d_leaf)
 
         def _unblock(new_states):
-            new_x = [_leaf_unblocks(ns.x, lx)
-                     for ns, lx in zip(new_states, leaves_x)]
-            new_algo = {f: [_leaf_unblocks(getattr(ns, f), lx)
-                            for ns, lx in zip(new_states, leaves_x)]
+            new_x = [_leaf_unblocks(ns.x, lx, v)
+                     for ns, lx, v in zip(new_states, leaves_x, views)]
+            new_algo = {f: [_leaf_unblocks(getattr(ns, f), lx, v)
+                            for ns, lx, v in zip(new_states, leaves_x, views)]
                         for f in leaves_algo}
             return new_x, new_algo
 
@@ -754,16 +836,10 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
                     # one shared message (per-lane dither — see
                     # gossip_payloads)
                     msgs = pmean_intra(msgs)
-                payloads = []
+                payloads, wire_bits = encode_payloads(wire_keys, msgs,
+                                                      wire_dims)
                 bits_total = jnp.zeros((), jnp.float32)
-                for kk, msg, d_leaf in zip(wire_keys, msgs, wire_dims):
-                    if comp is not None:
-                        payload, bits = comp.encode_blocks(
-                            kk, msg, d_leaf, interpret=dc.interpret)
-                    else:
-                        payload = {"values": msg}
-                        bits = jnp.asarray(d_leaf * 32, jnp.float32)
-                    payloads.append(payload)
+                for bits in wire_bits:
                     bits_total = bits_total + bits
 
             with jax.named_scope("stage.gossip"):
@@ -858,4 +934,14 @@ def make_train_step(cfg, mesh, prof: shr.ShardingProfile, dc: DistConfig):
                                where="dist train step")
         return new, metrics
 
+    # the layout each leaf of the model takes, counted once from the
+    # parameters' shapes (None: allreduce has no engine buffers)
+    step.layout = None
+    if eng is not None:
+        replica = jax.eval_shape(functools.partial(tfm.init_params, cfg),
+                                 jax.ShapeDtypeStruct((2,), jnp.uint32))
+        stacked = [jax.ShapeDtypeStruct((A,) + l.shape, l.dtype)
+                   for l in jax.tree_util.tree_leaves(replica)]
+        step.layout = _layout_counts(
+            stacked, [_leaf_view(l.shape, dc.block, comp) for l in stacked])
     return step

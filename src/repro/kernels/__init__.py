@@ -45,11 +45,23 @@ encode/decode wire stage, dense|ring gossip) is the single producer of
 buffers in this layout; every engine state is a NamedTuple of such
 buffers.
 
+Rows of k blocks: a buffer may also be ``(rows, k * block)``, block j of a
+row being its lane segment j.  dist/trainer.py hands each model leaf whose
+last dim C is a multiple of ``block`` to the kernels so, as ``(rows, C)``
+rows of its own last dim: a bitcast of the leaf in the TPU's (8, 128)
+tiles, where ``(nb, block)`` rows of its flattening would be a copy.
+lead_update is elementwise and takes any width; quantize.encode /
+quantize.decode reduce each lane segment on its own scale (scales
+``(rows, k)``).  quantize.row_tile sizes their tiles, keeping a tile's VMEM
+bytes those of ``(256, 512)``: 256 rows at 512 lanes, 16 at 8192.
+
 Encoded-payload interface (codes on the wire)
 ---------------------------------------------
 Every compressor exposes a flat wire path over the same blocked layout
 (core/compression.py): ``encode_blocks(key, (n, nb, block), dim) ->
-(payload, bits)`` / ``decode_blocks(payload)``.  The payload is the ONLY
+(payload, bits)`` / ``decode_blocks(payload)``; those whose blocks are
+contiguous lane segments (``row_segments``: QuantizePNorm, Identity) also
+take ``(n, rows, k * block)`` rows.  The payload is the ONLY
 thing that may cross agents — the gossip stages (dist/trainer.py's
 per-round ppermute exchange on mesh axes, core/gossip.py
 EncodedNeighborGossip on the flat agent axis) move payload leaves between

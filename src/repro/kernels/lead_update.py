@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.dispatch import resolve_backend
-from repro.kernels.quantize import DEFAULT_TILE_B, fit_tile
+from repro.kernels.quantize import DEFAULT_TILE_B, fit_tile, row_tile
 
 
 def _lead_update_kernel(eta_ref, gamma_ref, alpha_ref,
@@ -47,9 +47,18 @@ def _lead_update_kernel(eta_ref, gamma_ref, alpha_ref,
 
 
 def lead_update(x, g, d, h, hw, qh, wqh, eta, gamma, alpha, *,
-                tile_b: int = DEFAULT_TILE_B, interpret: Optional[bool] = None):
-    """All tensors (nb, block) f32, any nb (tile_b caps the rows per grid
-    step; see quantize.fit_tile); scalars broadcastable to (1, 1) f32.
+                tile_b: int = DEFAULT_TILE_B, in_place: bool = False,
+                interpret: Optional[bool] = None):
+    """All tensors (rows, width) f32, any rows and any width (the body is
+    elementwise): the flat engines' (nb, block) rows, or a trainer leaf's
+    (rows, last dim).  ``tile_b`` caps the rows per grid step at 512 lanes
+    (see quantize.row_tile: the tile's VMEM bytes stay those of a
+    (tile_b, 512) tile); scalars broadcastable to (1, 1) f32.
+
+    ``in_place`` writes the results over the buffers of x, d, h and hw.
+    Where the caller donates those (the trainer's step), XLA then needs no
+    copy of them to keep the call from writing over its own operands; it
+    still copies one that is read again after the call.
 
     Returns (x_new, d_new, h_new, hw_new)."""
     backend = resolve_backend(interpret)
@@ -59,13 +68,13 @@ def lead_update(x, g, d, h, hw, qh, wqh, eta, gamma, alpha, *,
                                          jnp.asarray(eta, jnp.float32),
                                          jnp.asarray(gamma, jnp.float32),
                                          jnp.asarray(alpha, jnp.float32)))
-    nb, block = x.shape
-    tile_b = fit_tile(nb, tile_b)
-    grid = (pl.cdiv(nb, tile_b),)
+    rows, width = x.shape
+    tile_b = row_tile(rows, width, tile_b)
+    grid = (pl.cdiv(rows, tile_b),)
     scal = lambda v: jnp.asarray(v, jnp.float32).reshape(1, 1)
-    tile = pl.BlockSpec((tile_b, block), lambda i: (i, 0))
+    tile = pl.BlockSpec((tile_b, width), lambda i: (i, 0))
     smem = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    out_sds = jax.ShapeDtypeStruct((nb, block), jnp.float32)
+    out_sds = jax.ShapeDtypeStruct((rows, width), jnp.float32)
     return pl.pallas_call(
         _lead_update_kernel,
         grid=grid,
@@ -73,6 +82,8 @@ def lead_update(x, g, d, h, hw, qh, wqh, eta, gamma, alpha, *,
         out_specs=[tile] * 4,
         out_shape=[out_sds] * 4,
         interpret=(backend == "interpret"),
+        # x, d, h, hw -> x_new, d_new, h_new, hw_new
+        input_output_aliases={3: 0, 5: 1, 6: 2, 7: 3} if in_place else {},
         name="lead_update",
     )(scal(eta), scal(gamma), scal(alpha), x, g, d, h, hw, qh, wqh)
 

@@ -8,22 +8,35 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
-def quantize_encode_ref(x: jnp.ndarray, u: jnp.ndarray, bits: int):
+def quantize_encode_ref(x: jnp.ndarray, u: jnp.ndarray, bits: int,
+                        block=None):
     """Blockwise inf-norm b-bit stochastic quantization (paper Thm 3, p=inf).
 
-    x, u: (nb, block) f32; u ~ U[0,1).  Returns (code int8, scale f32 (nb,1)).
+    x, u: (rows, width) f32 with ``width // block`` blocks a row (``block``
+    defaults to the width); u ~ U[0,1).  Returns (code int8 (rows, width),
+    scale f32 (rows, width // block)).
     """
+    rows, width = x.shape
+    k = 1 if block is None else width // block
+    x = x.reshape(rows, k, width // k)
+    u = u.reshape(rows, k, width // k)
     scale = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     safe = jnp.where(scale > 0, scale, 1.0)
     lvl = jnp.floor((2.0 ** (bits - 1)) * jnp.abs(x) / safe + u)
     lvl = jnp.minimum(lvl, 2.0 ** (bits - 1))
     code = (jnp.sign(x) * lvl).astype(jnp.int8)
-    return code, jnp.where(scale > 0, scale, 0.0).astype(jnp.float32)
+    return (code.reshape(rows, width),
+            jnp.where(scale > 0, scale, 0.0).astype(jnp.float32).reshape(rows, k))
 
 
 def quantize_decode_ref(code: jnp.ndarray, scale: jnp.ndarray, bits: int):
-    """Inverse of quantize_encode_ref: (nb, block) f32 values."""
-    return scale * (2.0 ** (1 - bits)) * code.astype(jnp.float32)
+    """Inverse of quantize_encode_ref: (rows, width) f32 values, each of the
+    k = scale.shape[-1] lane segments of a row on its own scale."""
+    rows, width = code.shape
+    k = scale.shape[-1]
+    vals = (scale.reshape(rows, k, 1) * (2.0 ** (1 - bits))
+            * code.reshape(rows, k, width // k).astype(jnp.float32))
+    return vals.reshape(rows, width)
 
 
 def lead_update_ref(x, g, d, h, hw, qh, wqh, eta, gamma, alpha):

@@ -155,8 +155,13 @@ def build(args: argparse.Namespace) -> Run:
         # new state reuse its buffers (x, h, hw, d of a full-width agent
         # do not fit a chip twice).  The state leaves the step with the
         # layout it came in with, so the next call hits the same executable.
-        step_fn = jax.jit(make_train_step(cfg, mesh, prof, dc),
-                          donate_argnums=0,
+        step = make_train_step(cfg, mesh, prof, dc)
+        if step.layout is not None:
+            print(f"leaf layout: {step.layout['tiled_leaves']} of "
+                  f"{step.layout['tiled_leaves'] + step.layout['padded_leaves']}"
+                  f" leaves tiled, {step.layout['padded_elements']} elements "
+                  "on the padded path")
+        step_fn = jax.jit(step, donate_argnums=0,
                           out_shardings=(shardings, NamedSharding(mesh, P())))
         loss_fn = jax.jit(jax.vmap(lambda p, b: tfm.loss_fn(p, cfg, b)[0]))
     ds = LMStreamConfig(vocab=cfg.vocab, seq_len=args.seq_len,

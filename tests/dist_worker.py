@@ -26,6 +26,10 @@ Cases:
                          rounds, dropped_links metric) trains finite, and
                          a kill-at-step-4 checkpoint-resume reproduces the
                          continuous run bit for bit
+    leaf_layout <sub>    the trainer's tiled leaf view equals the padded
+                         path bit for bit (sub: ring, wire_pack,
+                         hierarchical, comm_interval, randk, choco,
+                         pallas)
 """
 import dataclasses
 import os
@@ -649,6 +653,98 @@ def case_timevarying_multihost():
     assert dropped > 0 and finite
 
 
+def case_leaf_layout():
+    """The trainer's tiled leaf view against the padded path, bit for bit,
+    for the sub-case named by the next argument.
+
+    A small LEAD model on 4 agents whose leaves have last dims of 512 (the
+    embedding, wq, wo, w_down) and 1024 (w_gate, w_up), two layers stacked
+    in each, 1-D norms, and kv projections of 128 lanes and an untied head
+    of 256, which no block of 512 divides (the padded path).  The sub-case
+    runs 3 steps twice from one state, batches and keys: as the trainer
+    takes each leaf, and with the view selector patched to always take the
+    padded path.  "pallas" runs the Pallas kernels in interpret mode, so the
+    quantize kernel encodes and decodes inside the trainer's shard_map on
+    each device's own agent.  Prints one LEAF_LAYOUT line:
+    the fields (params, the engine's state fields, bits_per_agent) that
+    differ, both runs' layout counts and the bits of each step."""
+    import json
+
+    from repro.core.compression import RandK
+    from repro.dist import trainer as tr
+
+    cfg = dataclasses.replace(
+        get_config("granite-3-2b").reduced(d_model=512, vocab=256),
+        kv_heads=1, head_dim=128, d_ff=1024)
+    flat = ((4, 1), ("data", "model"))
+    (mesh_shape, axes), dc_kwargs = {
+        "ring": (flat, {}),
+        "wire_pack": (flat, {"wire_pack": True}),
+        "hierarchical": (((2, 2), ("pod", "data")),
+                         {"topology": topology.hierarchical(
+                             topology.ring(2), 2)}),
+        "comm_interval": (flat, {"topology": topology.ring(4)
+                                 .with_interval(2)}),
+        "randk": (flat, {"compressor": RandK(ratio=0.25)}),
+        "pallas": (flat, {"interpret": True}),
+        "choco": (flat, {"algorithm": "choco"}),
+    }[sys.argv[2]]
+
+    def run():
+        mesh = jax.make_mesh(mesh_shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes),
+                             devices=jax.devices()[:4])
+        prof = shr.make_profile(cfg, mesh.axis_names)
+        shr.set_mesh_for_rules(mesh)
+        dc = DistConfig(**{"algorithm": "lead", **dc_kwargs})
+        key = jax.random.PRNGKey(0)
+        sds = jax.eval_shape(
+            lambda k: init_train_state(cfg, mesh, prof, dc, k), key)
+        shardings = state_shardings(cfg, mesh, prof, sds)
+        ds = LMStreamConfig(vocab=cfg.vocab, seq_len=16, batch_per_agent=2,
+                            n_agents=4)
+        bspec = NamedSharding(mesh, shr.train_batch_spec(prof))
+        with jax.set_mesh(mesh):
+            state = jax.jit(lambda k: init_train_state(cfg, mesh, prof, dc, k),
+                            out_shardings=shardings)(key)
+            fn = make_train_step(cfg, mesh, prof, dc)
+            step = jax.jit(fn)
+            bits = []
+            for i in range(3):
+                b = jax.device_put(lm_batch(ds, i), bspec)
+                state, m = step(state, b, jax.random.fold_in(key, i))
+                bits.append(np.asarray(m["bits_per_agent"]))
+        out = {"params": state.params, **state.algo, "bits_per_agent": bits}
+        return jax.device_get(out), fn.layout
+
+    tiled, layout = run()
+    view = tr._leaf_view
+    tr._leaf_view = lambda *a, **k: None
+    try:
+        padded, padded_layout = run()
+    finally:
+        tr._leaf_view = view
+    pairs = {f: list(zip(jax.tree_util.tree_leaves(tiled[f]),
+                         jax.tree_util.tree_leaves(padded[f])))
+             for f in tiled}
+    differ = [f for f, ab in pairs.items()
+              if not all(np.array_equal(a, b) for a, b in ab)]
+    # elements of the state off by more than 1e-4 of the largest parameter
+    tol = 1e-4 * max(float(np.max(np.abs(a)))
+                     for a, _ in pairs["params"])
+    deviating = sum(int(np.sum(np.abs(np.asarray(a, np.float64)
+                                      - np.asarray(b, np.float64)) > tol))
+                    for f, ab in pairs.items() if f != "bits_per_agent"
+                    for a, b in ab)
+    elements = sum(np.size(a) for f, ab in pairs.items()
+                   if f != "bits_per_agent" for a, _ in ab)
+    print("LEAF_LAYOUT", json.dumps({
+        "differ": differ, "fields": sorted(tiled), "layout": layout,
+        "padded_layout": padded_layout, "deviating": deviating,
+        "elements": elements,
+        "bits": [float(b) for b in tiled["bits_per_agent"]],
+        "padded_bits": [float(b) for b in padded["bits_per_agent"]]}))
+
 if __name__ == "__main__":
     case = sys.argv[1]
     {"nids_equivalence": case_nids_equivalence,
@@ -660,5 +756,6 @@ if __name__ == "__main__":
      "cgt_train": case_cgt_train,
      "faulted_checkpoint_resume": case_faulted_checkpoint_resume,
      "topology_multihost": case_topology_multihost,
-     "timevarying_multihost": case_timevarying_multihost}[case]()
+     "timevarying_multihost": case_timevarying_multihost,
+     "leaf_layout": case_leaf_layout}[case]()
     print("PASS", case)

@@ -120,6 +120,45 @@ def test_encode_blocks_matches_compress_rows(key):
         assert float(bits) > 0
 
 
+@pytest.mark.parametrize("p", [np.inf, 2])
+@pytest.mark.parametrize("width", [1024, 2048])
+def test_encode_blocks_row_segments_match_blocks(p, width, key):
+    """QuantizePNorm on (n, R, k * 512) rows — block j of a row is its lane
+    segment j — gives the codes, scales and bits of the same buffer as
+    (n, R * k, 512) blocks, exactly, and decodes to the same values."""
+    n, R, block = 3, 40, 512
+    comp = QuantizePNorm(bits=2, p=p, block=block)
+    x = jax.random.normal(key, (n, R, width))
+    x = x.at[0, 3].set(0.0)                    # a row of all-zero blocks
+    rows_pl, rows_bits = comp.encode_blocks(key, x, R * width)
+    blk = x.reshape(n, R * width // block, block)
+    blk_pl, blk_bits = comp.encode_blocks(key, blk, R * width)
+    k = width // block
+    assert rows_pl["scale"].shape == (n, R, k)
+    np.testing.assert_array_equal(np.asarray(rows_pl["code"]).reshape(blk.shape),
+                                  np.asarray(blk_pl["code"]))
+    np.testing.assert_array_equal(np.asarray(rows_pl["scale"]).reshape(n, -1),
+                                  np.asarray(blk_pl["scale"])[..., 0])
+    assert float(rows_bits) == float(blk_bits) == \
+        comp.wire_bits(R * width)
+    np.testing.assert_array_equal(
+        np.asarray(comp.decode_blocks(rows_pl)).reshape(blk.shape),
+        np.asarray(comp.decode_blocks(blk_pl)))
+
+
+@pytest.mark.parametrize("shape", [(4, 2048), (3, 8192), (1, 512)])
+def test_dither_rows_equal_block_draw(shape, key):
+    """The threefry counter is the row-major index: a (R, C) draw is the
+    (R * C / 512, 512) block matrix's draw reshaped, bit for bit — what
+    lets the trainer's tiled view keep the padded path's dither."""
+    R, C = shape
+    for kk in jax.random.split(key, 3):
+        rows = jax.random.uniform(kk, (R, C), jnp.float32)
+        blocks = jax.random.uniform(kk, (R * C // 512, 512), jnp.float32)
+        np.testing.assert_array_equal(np.asarray(rows),
+                                      np.asarray(blocks).reshape(R, C))
+
+
 def test_identity_exact(key):
     x = jax.random.normal(key, (77,))
     assert bool(jnp.all(Identity().compress(key, x) == x))

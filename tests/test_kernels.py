@@ -188,6 +188,74 @@ def test_ragged_lead_update_matches_unpadded(rows, key):
         np.testing.assert_array_equal(np.asarray(p), np.asarray(w))
 
 
+@pytest.mark.parametrize("in_place", [False, True])
+@pytest.mark.parametrize("rows,width", [(128, 2048), (130, 2048), (32, 8192),
+                                        (40, 8192)])
+def test_lead_update_on_wide_rows_matches_ref(rows, width, in_place, key):
+    """A trainer leaf's (rows, last dim) view: the elementwise body on
+    tiles of row_tile(rows, width) rows (64 at 2048, 16 at 8192; a ragged
+    last tile at 130 and 40 rows) equals the oracle, and the same call on
+    the data cut into (rows * width / 512, 512) blocks bit for bit, in
+    place or not."""
+    from repro.kernels import lead_update as lu
+    from repro.kernels.quantize import row_tile
+    assert row_tile(rows, width) < rows
+    arrs = [jax.random.normal(jax.random.fold_in(key, i), (rows, width))
+            for i in range(7)]
+    got = lu.lead_update(*arrs, 0.1, 1.0, 0.5, in_place=in_place,
+                         interpret=True)
+    want = ref.lead_update_ref(*arrs, 0.1, 1.0, 0.5)
+    blocks = lu.lead_update(*[a.reshape(-1, 512) for a in arrs], 0.1, 1.0,
+                            0.5, interpret=True)
+    for g, w, b, nm in zip(got, want, blocks, ["x", "d", "h", "hw"]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5,
+                                   err_msg=nm)
+        np.testing.assert_array_equal(np.asarray(g),
+                                      np.asarray(b).reshape(rows, width),
+                                      err_msg=nm)
+
+
+def test_row_tile_keeps_tile_bytes():
+    """row_tile keeps a tile's VMEM bytes those of a (256, 512) tile: 256
+    rows at 512 lanes (the flat engines' tile, unchanged), 16 at 8192, and
+    at least 8 rows up to MAX_WIDTH; under fit_tile's rule."""
+    from repro.kernels.quantize import MAX_WIDTH, fit_tile, row_tile
+    assert row_tile(10_000, 512) == fit_tile(10_000, 256) == 256
+    assert row_tile(10_000, 256) == 256
+    assert row_tile(10_000, 2048) == 64 and row_tile(10_000, 8192) == 16
+    assert row_tile(10_000, MAX_WIDTH) == 8
+    assert row_tile(12, 8192) == 12 and row_tile(4, 2048) == 4
+    for w in (512, 1024, 1536, 2048, 8192, MAX_WIDTH):
+        assert row_tile(10_000, w) * w <= 256 * 512
+
+
+@pytest.mark.parametrize("rows,width", [(128, 2048), (130, 2048), (4, 2048),
+                                        (40, 8192)])
+@pytest.mark.parametrize("bits", [2, 4])
+def test_quantize_row_segments_match_ref(rows, width, bits, key):
+    """quantize.encode / decode on rows of width // 512 blocks: each lane
+    segment on its own scale, as the oracle and as the same rows cut into
+    (rows * k, 512) blocks — codes and scales bit for bit."""
+    from repro.kernels import quantize as q
+    x = jax.random.normal(key, (rows, width))
+    x = x.at[1, :512].set(0.0)                 # an all-zero block
+    u = jax.random.uniform(jax.random.fold_in(key, 1), (rows, width))
+    code, scale = q.encode(x, u, bits=bits, block=512, interpret=True)
+    rc, rs = ref.quantize_encode_ref(x, u, bits, 512)
+    bc, bs = q.encode(x.reshape(-1, 512), u.reshape(-1, 512), bits=bits,
+                      interpret=True)
+    assert scale.shape == (rows, width // 512)
+    for got, want in ((code, rc), (scale, rs), (code, bc.reshape(rows, width)),
+                      (scale, bs.reshape(rows, -1))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    vals = q.decode(code, scale, bits=bits, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(vals), np.asarray(ref.quantize_decode_ref(code, scale, bits)))
+    np.testing.assert_array_equal(
+        np.asarray(vals).reshape(-1, 512),
+        np.asarray(q.decode(bc, bs, bits=bits, interpret=True)))
+
+
 @pytest.mark.parametrize("rows", [12, 300, 1001])
 def test_ragged_randk_encode_matches_unpadded(rows, key):
     from repro.kernels import sparsify

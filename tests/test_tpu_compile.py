@@ -75,6 +75,33 @@ def test_lead_apply_stage_at_granite_embedding(one_chip):
     _assert_kernel(apply, *[buf] * 7, k)
 
 
+# the trainer's tiled views of granite-3-2b's leaves: the embedding and a
+# gate/up projection (4 layers of (2048, 8192) merged into 8192 rows)
+GRANITE_VIEWS = [(49155, 2048), (8192, 8192)]
+
+
+@pytest.mark.parametrize("rows,width", GRANITE_VIEWS)
+def test_lead_update_at_granite_views(one_chip, rows, width):
+    """lead_update on a leaf's (rows, last dim) view, in place as the
+    trainer calls it: tiles of row_tile's 64 and 16 rows, the embedding's
+    ragged in its last."""
+    buf = _sds(one_chip, (rows, width))
+    s = _sds(one_chip, (), jnp.float32)
+    _assert_kernel(lambda x, g, d, h, hw, q_, wq, e, ga, al: lu.lead_update(
+        x, g, d, h, hw, q_, wq, e, ga, al, in_place=True, interpret=False),
+        *[buf] * 7, s, s, s)
+
+
+@pytest.mark.parametrize("rows,width", GRANITE_VIEWS)
+def test_quantize_row_segments_at_granite_views(one_chip, rows, width):
+    buf = _sds(one_chip, (rows, width))
+    _assert_kernel(lambda x, u: q.encode(x, u, bits=2, block=BLOCK,
+                                         interpret=False), buf, buf)
+    _assert_kernel(lambda c, s: q.decode(c, s, bits=2, interpret=False),
+                   _sds(one_chip, (rows, width), jnp.int8),
+                   _sds(one_chip, (rows, width // BLOCK)))
+
+
 def test_lead_diff_encode_at_engine_rows(one_chip):
     rows = _sds(one_chip, (ENGINE_ROWS, BLOCK))
     eta = _sds(one_chip, (), jnp.float32)
@@ -124,3 +151,47 @@ def test_kv_encode_rows_with_odd_divisor(one_chip):
     pages = _sds(one_chip, (101, 16, 1, 96))
     _assert_kernel(lambda x: kv_quant.encode_rows(x, spec, interpret=False),
                    pages)
+
+
+def test_train_step_on_four_chips_runs_its_kernels_per_agent(topo):
+    """The LEAD training step on a 4,1 mesh of the described v5e 2x2 (one
+    agent a chip), at small widths whose leaves take the tiled view: XLA
+    cannot partition a Pallas call, so the quantize and lead_update
+    kernels run inside the step's shard_maps on each chip's own agent,
+    and nothing is gathered across chips; only the payloads travel."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.registry import get_config
+    from repro.dist import sharding as shr
+    from repro.dist.trainer import (init_train_state, make_train_step,
+                                    state_shardings)
+
+    cfg = dataclasses.replace(
+        get_config("granite-3-2b").reduced(d_model=512, vocab=256),
+        kv_heads=1, head_dim=128, d_ff=1024)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+    prof = shr.make_profile(cfg, mesh.axis_names)
+    shr.set_mesh_for_rules(mesh)
+    dc = DistConfig(algorithm="lead", bits=2, interpret=False)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    sds = jax.eval_shape(
+        lambda k: init_train_state(cfg, mesh, prof, dc, k), key)
+    sds = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        sds, state_shardings(cfg, mesh, prof, sds))
+    bspec = NamedSharding(mesh, shr.train_batch_spec(prof))
+    batch = {k: jax.ShapeDtypeStruct((4, 2, 64), jnp.int32, sharding=bspec)
+             for k in ("tokens", "labels")}
+    with jax.set_mesh(mesh):
+        step = make_train_step(cfg, mesh, prof, dc)
+        text = jax.jit(step, donate_argnums=0).lower(
+            sds, batch, key).compile().as_text()
+    assert step.layout["tiled_leaves"] > 0
+    for kernel in ("quantize_encode", "quantize_decode", "lead_update"):
+        assert kernel in text, kernel
+    assert "all-gather" not in text
+    assert "collective-permute" in text
