@@ -10,9 +10,11 @@ with each fault the cell can have planted in the program.  The controls:
 for a training cell the trainer's own lower-precision paths, bf16 state and
 compute ("control") and bf16 compute with f32 state ("control_compute");
 for an engine cell the plain reference computed in bf16.  The faults: for a
-training cell half of the batch left out, and the iterate left unchanged
-by every step after the checked ones (a state left unchanged from the
-first step reads 1 by the measure and needs no run).  Prints one JSON line
+training cell half of the batch left out, the iterate left unchanged by
+every step after the checked ones (a state left unchanged from the first
+step reads 1 by the measure and needs no run) and, where agents gossip,
+the exchange between them cut: every link dropped, so each agent mixes its
+own payload alone.  Prints one JSON line
 per run and a summary: per number, the largest sound reading and the
 smallest control and fault readings.
 """
@@ -34,12 +36,13 @@ BF16_TRAIN = {"compute_dtype": "bfloat16", "state_dtype": "bfloat16"}
 BF16_COMPUTE = {"compute_dtype": "bfloat16"}
 
 
-def programs(system_name: str, system):
+def programs(system_name: str, system, agents: int = 1):
     """{kind: the program to run in the cell's place (None: as it is)};
     the kinds that start with "control" are controls, the rest faults."""
     import jax
     import jax.numpy as jnp
     if system_name == "train":
+        from repro.core.faults import FaultModel
         class HalfBatch(system.Program):
             """Half of each batch left out: the mean over the rest."""
 
@@ -68,11 +71,17 @@ def programs(system_name: str, system):
 
                 self.run.step_fn = step_fn
 
-        return {"control": functools.partial(system.Program,
-                                             dc_overrides=BF16_TRAIN),
-                "control_compute": functools.partial(
-                    system.Program, dc_overrides=BF16_COMPUTE),
-                "half_batch": HalfBatch, "still_in_window": StillInWindow}
+        out = {"control": functools.partial(system.Program,
+                                            dc_overrides=BF16_TRAIN),
+               "control_compute": functools.partial(
+                   system.Program, dc_overrides=BF16_COMPUTE),
+               "half_batch": HalfBatch, "still_in_window": StillInWindow}
+        if agents > 1:
+            # the trainer's own link faults, every link down every step
+            out["gossip_cut"] = functools.partial(
+                system.Program,
+                dc_overrides={"faults": FaultModel(link_drop=1.0)})
+        return out
     return {"control": functools.partial(system.Reference,
                                          dtype=jnp.bfloat16)}
 
@@ -98,7 +107,7 @@ def main(argv=None):
     jax.monitoring.register_event_duration_secs_listener(clock)
     name = cell.config["system"]
     system = importlib.import_module(f"bench.systems.{name}")
-    alts = programs(name, system)
+    alts = programs(name, system, cell.traffic.get("agents", 1))
 
     controls = [k for k in alts if k.startswith("control")]
     plan = [("sound", s) for s in _ints(args.seeds)]

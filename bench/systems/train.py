@@ -299,76 +299,126 @@ def _ref_leaf_step(x, g, h, hw, d, key, W, *, bits, block, eta, gamma,
     return tuple(a.reshape(A, -1)[:, :n].reshape(shape) for a in new)
 
 
+def homes(sizes, n_devices: int) -> list:
+    """Each leaf's device index: the largest leaves first, each to the
+    device that holds the fewest elements so far."""
+    load, out = [0] * n_devices, [0] * len(sizes)
+    for i in sorted(range(len(sizes)), key=lambda i: -sizes[i]):
+        out[i] = min(range(n_devices), key=lambda j: load[j])
+        load[out[i]] += sizes[i]
+    return out
+
+
+def _blocks(a, block: int):
+    """(A, ...) -> (A, rows, block): each agent's leaf, flattened, in the
+    quantizer's blocks, the last one padded with zeros."""
+    n = int(np.prod(a.shape[1:]))
+    rows = counts.blocks(n, block)
+    return jnp.pad(a.reshape(a.shape[0], -1),
+                   ((0, 0), (0, rows * block - n))).reshape(-1, rows, block)
+
+
+def _flat(a, n: int):
+    """(A, rows, block) -> (A, n): each agent's n elements, unpadded."""
+    return a.reshape(a.shape[0], -1)[:, :n]
+
+
 def reference_readings(config: dict, traffic: dict, seed_key) -> dict:
-    """The checked steps retraced by the plain reference from the seed."""
+    """The checked steps retraced by the plain reference from the seed.
+
+    A leaf's four LEAD fields (x, h, hw, d), each stacked over the agents in
+    the quantizer's blocks (A, rows, block), lie on the leaf's home device;
+    the leaves spread over the devices by size, so that four agents' state
+    (22 GB at granite-3-2b widths) need not fit one chip.  Agent a's
+    gradient is taken on device a % n from its own slice of every leaf;
+    each leaf's LEAD step runs on its home, where its mix sees every agent.
+    In blocks, the step relayouts no field (the embedding's rows, 49155,
+    are no multiple of the TPU's tile of 8).  On one device every leaf lies
+    there."""
+    devices = jax.devices()
     m = ref_tf.Frozen(config["model"])
     alg = config["algorithm"]
     if alg["name"] != "lead" or alg["topology"] != "ring":
         raise NotImplementedError("the reference runs LEAD on a ring")
-    A = traffic["agents"]
-    W = jnp.asarray(ref_lead.ring(A), jnp.float32)
+    A, block = traffic["agents"], alg["block"]
+    W = np.asarray(ref_lead.ring(A), np.float32)
     eta, gamma, alpha = (float(alg[k]) for k in ("eta", "gamma", "alpha"))
     wkey, dkey, skey = _keys(seed_key)
     gen = jax.jit(generator(traffic, m["vocab"], A))
-    w = jax.jit(ref_tf.weights, static_argnums=1)(wkey, m)
-    stack = jax.jit(lambda t: jax.tree_util.tree_map(
-        lambda l: jnp.broadcast_to(l[None], (A,) + l.shape), t))
-    x = stack(w)
-    del w
+    stacked = jax.jit(lambda l: _blocks(
+        jnp.broadcast_to(l[None], (A,) + l.shape), block))
+    unblocks = jax.jit(lambda a, shape: _flat(a, int(np.prod(shape))).reshape(
+        (a.shape[0],) + shape), static_argnums=1)
+    w, treedef = jax.tree_util.tree_flatten(
+        jax.jit(ref_tf.weights, static_argnums=1)(wkey, m))
+    shapes = [l.shape for l in w]
+    sizes = [l.size for l in w]
+    home = [devices[j] for j in homes(sizes, len(devices))]
+    Ws = {dev: jax.device_put(W, dev) for dev in set(home)}
     # LEAD's consensus start: H = X0, H_w = W X0 = X0, D = 0
-    h = jax.tree_util.tree_map(jnp.copy, x)
-    hw = jax.tree_util.tree_map(jnp.copy, x)
-    d = jax.tree_util.tree_map(jnp.zeros_like, x)
+    fields = []                                  # per leaf: [x, h, hw, d]
+    for l, dev in zip(w, home):
+        x = stacked(jax.device_put(l, dev))
+        fields.append([x, jnp.copy(x), jnp.copy(x), jnp.zeros_like(x)])
+    del w, x
     out = {"loss": [], "grad_norm": [], "bits": []}
-    wire = sum(counts.quantizer_wire_bits(n, alg["bits"], alg["block"])
-               for n in (int(np.prod(l.shape[1:]))
-                         for l in jax.tree_util.tree_leaves(x)))
+    wire = sum(counts.quantizer_wire_bits(n, alg["bits"], block)
+               for n in sizes)
     for k in range(CHECKED_STEPS):
         b = gen(dkey, k)
         losses, grads = [], []
         for a in range(A):
-            loss, g = _ref_grad(x, b["tokens"], b["labels"], m=m, agent=a)
-            losses.append(float(loss))
-            grads.append(g)
-        g = jax.jit(lambda gs: jax.tree_util.tree_map(
-            lambda *ls: jnp.stack(ls), *gs), donate_argnums=0)(grads)
+            dev = devices[a % len(devices)]
+
+            def part(leaf):
+                return jax.device_put(leaf[a:a + 1], dev)
+
+            xa = jax.tree_util.tree_unflatten(treedef, [
+                unblocks(part(f[0]), shape)
+                for f, shape in zip(fields, shapes)])
+            loss, g = _ref_grad(xa, part(b["tokens"]), part(b["labels"]),
+                                m=m, agent=0)
+            losses.append(loss)
+            grads.append([_blocks(l[None], block)
+                          for l in jax.tree_util.tree_leaves(g)])
+        del xa, g
+        gl = [jnp.concatenate([jax.device_put(ga[i], dev) for ga in grads])
+              for i, dev in enumerate(home)]
         del grads
-        gl = jax.tree_util.tree_leaves(g)
         per = np.stack([np.asarray(jnp.sqrt(jnp.sum(jnp.square(
-            l.reshape(A, -1)), 1))) for l in gl])
-        out["loss"].append(np.asarray(losses))
+            _flat(l, n)), 1))) for l, n in zip(gl, sizes)])
+        out["loss"].append(np.asarray([float(v) for v in losses]))
         out["grad_norm"].append(float(np.sqrt(np.sum(per.astype(np.float64)
                                                       ** 2))))
         out["bits"].append(float(wire))
         if k == 0:
             out["first_grad"] = per
-        leaves, treedef = jax.tree_util.tree_flatten(x)
-        keys = jax.random.split(step_key(skey, k), len(leaves))
-        fields = [jax.tree_util.tree_leaves(t) for t in (x, h, hw, d)]
-        new = [[], [], [], []]
-        for i in range(len(leaves)):
-            r = _ref_leaf_step(fields[0][i], gl[i], fields[1][i],
-                               fields[2][i], fields[3][i], keys[i], W,
-                               bits=alg["bits"], block=alg["block"],
-                               eta=eta, gamma=gamma, alpha=alpha)
-            for j in range(4):
-                new[j].append(r[j])
-        x, h, hw, d = (jax.tree_util.tree_unflatten(treedef, f) for f in new)
-        del g, gl, fields, new
-    w = jax.jit(ref_tf.weights, static_argnums=1)(wkey, m)
-    x0 = stack(w)
+        keys = jax.random.split(step_key(skey, k), len(fields))
+        for i, dev in enumerate(home):
+            x, h, hw, d = fields[i]
+            fields[i] = list(_ref_leaf_step(
+                x, gl[i], h, hw, d, jax.device_put(keys[i], dev), Ws[dev],
+                bits=alg["bits"], block=block, eta=eta, gamma=gamma,
+                alpha=alpha))
+        del gl, x, h, hw, d
+    w = jax.tree_util.tree_leaves(
+        jax.jit(ref_tf.weights, static_argnums=1)(wkey, m))
+    x0 = [stacked(jax.device_put(l, dev)) for l, dev in zip(w, home)]
     del w
 
-    def norms(f, base):
-        return np.stack([np.asarray(jnp.sqrt(jnp.sum(jnp.square(
-            (a - b).reshape(A, -1)), 1))) for a, b in zip(
-            jax.tree_util.tree_leaves(f), jax.tree_util.tree_leaves(base))])
+    def norms(j, zero=False):
+        return np.stack([np.asarray(jnp.sqrt(jnp.sum(jnp.square(_flat(
+            f[j] - (jnp.zeros_like(b) if zero else b), n)), 1)))
+            for f, b, n in zip(fields, x0, sizes)])
 
-    zero = jax.tree_util.tree_map(jnp.zeros_like, x0)
-    out["change"] = {"x": norms(x, x0), "h": norms(h, x0),
-                     "hw": norms(hw, x0), "d": norms(d, zero)}
+    out["change"] = {"x": norms(0), "h": norms(1), "hw": norms(2),
+                     "d": norms(3, zero=True)}
+    del x0
+    for f in fields:
+        del f[1:]
     out["loss"] = np.stack(out["loss"])
-    out["x"] = x
+    out["x"] = jax.tree_util.tree_unflatten(treedef, [
+        unblocks(f[0], shape) for f, shape in zip(fields, shapes)])
     return out
 
 
@@ -378,11 +428,13 @@ def _squared_distance(a, b):
 
 
 def distance(host_tree, device_tree) -> float:
-    """||a - b|| over every leaf, one leaf on the device at a time."""
+    """||a - b|| over every leaf, one leaf at a time on the device that
+    holds its ``b``."""
     total = 0.0
     for a, b in zip(jax.tree_util.tree_leaves(host_tree),
                     jax.tree_util.tree_leaves(device_tree)):
-        total += float(_squared_distance(jnp.asarray(a), b))
+        total += float(_squared_distance(
+            jax.device_put(a, next(iter(b.devices()))), b))
     return float(np.sqrt(total))
 
 
@@ -458,6 +510,10 @@ def measure(cell, seed_key, seconds: float, tracer=None,
             for n in counts.leaf_sizes(config["model"])),
         "lead_update_calls_per_step_per_chip": len(
             counts.leaf_sizes(config["model"])),
+        # the metered payload one agent sends each neighbour, times the
+        # neighbours it sends to (ring(4): 2; a ring of one: none)
+        "metered_wire_bytes_per_step_per_chip": want_bits / 8 * int(
+            np.count_nonzero(ref_lead.ring(prog.A)[0]) - 1),
     }
     x_end = jax.device_get(prog.state.params)
     prog.free()

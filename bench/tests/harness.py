@@ -35,6 +35,15 @@ TINY_CELLS = {
                    {"loss_gap": 1e-4, "grad_norm_gap": 1e-4,
                     "first_grad_gap": 1e-4, "change_gap": 1e-4,
                     "bits_gap": 0.0, "window_stall": 10.0}),
+    "tiny.train.ring4": ("tiny-train-ring4", TINY_TRAIN, "tiny.lm.a4",
+                         {"generator": "lm_stream", "agents": 4,
+                          "seq_len": 32, "batch_per_agent": 2,
+                          "block_size": 64},
+                         # a 2-bit level that flips on rounding reaches
+                         # the neighbours through the mix: about 1.4e-4
+                         {"loss_gap": 1e-4, "grad_norm_gap": 1e-4,
+                          "first_grad_gap": 1e-4, "change_gap": 1e-3,
+                          "bits_gap": 0.0, "window_stall": 10.0}),
     "tiny.engine": ("tiny-engine", TINY_ENGINE, "tiny.quadratic",
                     {"generator": "quadratic", "steps_per_call": 16},
                     {"deviating_share": 1e-2, "dist_ratio": 1e-2,
@@ -45,7 +54,8 @@ TINY_CELLS = {
 def add_cell(root, name, config_name, config, traffic_name, traffic,
              limits, spec=None):
     """Add a cell as data only: its configuration, traffic and limits as
-    new files, and its entries in BENCHMARK.json."""
+    new files, and its entries in BENCHMARK.json; one chip per agent of
+    its traffic."""
     b = root / "bench"
     (b / "configs" / f"{config_name}.json").write_text(json.dumps(config))
     (b / "traffic" / f"{traffic_name}.json").write_text(json.dumps(traffic))
@@ -55,7 +65,8 @@ def add_cell(root, name, config_name, config, traffic_name, traffic,
                             "file": f"bench/configs/{config_name}.json",
                             "reduced": [], "why": "test"})
     spec["workloads"].append({"name": name, "config": config_name,
-                              "traffic": traffic_name, "chips": 1,
+                              "traffic": traffic_name,
+                              "chips": traffic.get("agents", 1),
                               "why": "test"})
     system = config["system"]
     e2e = {"train": "train_tokens_per_s", "engine": "engine_steps_per_s"}

@@ -111,3 +111,31 @@ def test_quadratic_optimum_zeroes_the_summed_gradient():
     xs = quadratic.x_star(a, b)
     g = jnp.sum(quadratic.grad(a, b, xs[None]), axis=0)
     assert float(jnp.max(jnp.abs(g))) < 1e-5
+
+
+def test_leaves_go_home_by_size():
+    from bench.systems import train
+    assert train.homes([100, 67, 67, 67, 17, 17, 4, 4, 1], 4) == \
+        [0, 1, 2, 3, 1, 2, 3, 3, 3]
+    assert train.homes([5, 3, 1], 1) == [0, 0, 0]
+
+
+@pytest.mark.parametrize("agents", [1, 4])
+def test_the_reference_on_one_device_is_the_stacked_one(agents):
+    """On one device every leaf lies there, stacked over the agents: the
+    readings are those of the reference that held every agent's state so,
+    exactly (the one-chip cell's numbers do not move)."""
+    from bench.systems import train
+    from bench.tests import harness, stacked_reference
+    _, config, _, traffic, _ = harness.TINY_CELLS["tiny.train"]
+    traffic = dict(traffic, agents=agents)
+    key = seeds.seed_key(2 ** 35 + 1)
+    got = train.reference_readings(config, traffic, key)
+    want = stacked_reference.reference_readings(config, traffic, key)
+    for k in ("loss", "grad_norm", "first_grad", "bits"):
+        np.testing.assert_array_equal(got[k], want[k])
+    for f in want["change"]:
+        np.testing.assert_array_equal(got["change"][f], want["change"][f])
+    for a, b in zip(jax.tree_util.tree_leaves(got["x"]),
+                    jax.tree_util.tree_leaves(want["x"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -144,10 +144,11 @@ def test_traces_recorded_before_the_scopes_read_none(name):
 
 
 # Scoped traces recorded on a TPU v5 lite (bench/stages.py): one steady
-# step of the training cell and one call of two steps of the engine cell,
+# step of each training cell and one call of two steps of the engine cell,
 # each op with the op_name of its instruction in the compiled program.
 SCOPED_TRAIN = "train.granite3-2b.lead2.1chip.scoped.step.json.gz"
 SCOPED_ENGINE = "engine.lead2.ring8.d16M.scoped.2steps.json.gz"
+SCOPED_RING4 = "train.granite3-2b.lead2.ring4.scoped.step.json.gz"
 
 
 def _reader(name):
@@ -171,6 +172,37 @@ def test_recorded_training_step_by_stage():
     # the Pallas kernels carry their names (lead_update) into the trace
     assert trace.top_ops(t, 1)[0][0].startswith(
         "lead_update.11 tpu_custom_call(10)")
+
+
+def test_recorded_ring4_step_by_stage():
+    """One steady step of the four-chip ring: the stages averaged over the
+    four chips, and the gossip's collectives on every chip."""
+    t = trace.read(RECORDED / SCOPED_RING4)
+    assert sorted(t["devices"]) == [f"/device:TPU:{i}" for i in range(4)]
+    assert stages.stage_totals(t, 1) == {
+        "grad": 61.32434325, "encode": 25.6721095, "gossip": 17.399754,
+        "apply": 22.85940125, None: 11.42584725}
+    assert trace.busy_ns(t, P) == 138_682_989
+    ctx = {"trace": t, "traced_steps": 1}
+    for stage, want in (("grad", 61.32434325), ("encode", 25.6721095),
+                        ("gossip", 17.399754), ("apply", 22.85940125)):
+        assert _reader(f"{stage}_ms.train").read(ctx) == want
+    # the flights are on the first chip's async line alone; each chip's
+    # collective ops (44 collective-permute halves each way, the gradient
+    # norm's all-reduce) take about 2.4 ms of the step
+    assert [bool(t["async"].get(f"/device:TPU:{i}")) for i in range(4)] \
+        == [True, False, False, False]
+    assert _reader("collective_ms.train").read(ctx) == pytest.approx(
+        (2_404_274 + 2_413_614 + 2_399_115 + 2_373_398) / 4 / 1e6)
+    assert _reader("exposed_collective_share.train").read(ctx) == \
+        pytest.approx(100 * (2_488_356 + 2_413_614 + 2_399_115 + 2_373_398)
+                      / 4 / 148_578_744)
+    # a chip sends its 2-bit codes as int8 and its scales unpadded: 8/3 of
+    # the metered 3 bits an element, a little less for the scales
+    metered = 1_053_369_856 / 8 * 2
+    assert _reader("wire_bytes_ratio.train").read(
+        dict(ctx, metered_wire_bytes_per_step_per_chip=metered)) == \
+        pytest.approx(693_335_552 / metered)
 
 
 def test_recorded_engine_steps_by_stage():
